@@ -20,6 +20,9 @@ def main() -> None:
     parser.add_argument("--full", action="store_true", help="paper-scale (slow) settings")
     parser.add_argument("--only", type=str, default=None, help="comma-separated suite names")
     args = parser.parse_args()
+    from repro import compile_cache
+
+    compile_cache.enable()
     quick = not args.full
     wanted = args.only.split(",") if args.only else SUITES
 

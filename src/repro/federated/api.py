@@ -47,6 +47,7 @@ import numpy as np
 from repro.checkpoint import CheckpointManager
 from repro.core.family import FamilySpec
 from repro.federated.population import PopulationEngine, PopulationSpec, PopulationState
+from repro.federated.runtime import SERVER_STATE
 from repro.federated.scheduler import RoundScheduler, Scenario
 from repro.federated.strategy import StrategySpec
 from repro.launch.mesh import MeshSpec, build_mesh
@@ -54,7 +55,6 @@ from repro.launch.mesh import MeshSpec, build_mesh
 PyTree = Any
 
 _SPEC_FILE = "spec.json"
-_SERVER_KEYS = ("theta", "eta_G", "opt_server")
 
 # The deprecated out-of-band wire kwarg warns ONCE per process — sweeps
 # over many specs shouldn't drown their output in repeats.
@@ -507,6 +507,7 @@ class Experiment:
             self.server.state["theta"] = theta
         if eta_G is not None:
             self.server.state["eta_G"] = eta_G
+        self.server.place()
         return self
 
     # -- running ------------------------------------------------------------
@@ -698,7 +699,7 @@ class Experiment:
         state = self.server.state
         if lead:
             self.spec.save(os.path.join(directory, _SPEC_FILE))
-            mgr.save(self.round, {k: state[k] for k in _SERVER_KEYS})
+            mgr.save(self.round, {k: state[k] for k in SERVER_STATE})
         silo_state = self._silo_state_tree(state)
         if silo_state:
             if multi:
@@ -798,15 +799,9 @@ class Experiment:
 
         multi = exp.server.n_processes > 1
         state = exp.server.state
-        like = {k: state[k] for k in _SERVER_KEYS}
+        like = {k: state[k] for k in SERVER_STATE}
         restored = mgr.restore(step, like)
-        if multi:
-            # Host trees -> global arrays replicated over the new mesh
-            # (every process read the identical file).
-            restored = distributed.globalize(
-                restored, exp.server.mesh,
-                jax.sharding.PartitionSpec())
-        for k in _SERVER_KEYS:
+        for k in SERVER_STATE:
             state[k] = restored[k]
         silo_like = cls._silo_state_tree(state)
         if silo_like and multi:
@@ -841,6 +836,10 @@ class Experiment:
             # device count — padded rows are masked and never read).
             for k in silo_like:
                 state[k] = exp.server.pad_silo_axis(stacked[k])
+        # Restored host trees -> the round's input shardings on the new
+        # mesh (every process read the identical server files; silo rows
+        # built above are already global).
+        exp.server.place()
 
         exp.round = int(meta["round"])
         exp.comm.load_state(meta["comm"])

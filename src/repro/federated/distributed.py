@@ -55,10 +55,7 @@ def initialize(coordinator: Optional[str] = None,
         num_processes = int(os.environ[ENV_NUM_PROCS])
     if process_id is None and os.environ.get(ENV_PROC_ID):
         process_id = int(os.environ[ENV_PROC_ID])
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, ValueError):  # repro-lint: allow[R6] — jax cross-version feature shim (flag name varies), not a protocol probe
-        pass
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(
         coordinator_address=coordinator,
         num_processes=num_processes,
@@ -86,14 +83,19 @@ def globalize(tree: PyTree, mesh, pspec) -> PyTree:
     Every process passes the SAME host values (they are deterministic
     functions of the spec); ``make_array_from_callback`` materializes
     only this process's addressable shards, so a silo-sharded leaf
-    costs each host only its own rows.
+    costs each host only its own rows. Leaves that already are global
+    arrays with this sharding pass through unchanged.
     """
     from jax.sharding import NamedSharding
 
+    sharding = NamedSharding(mesh, pspec)
+
     def leaf(x):
+        if isinstance(x, jax.Array) and x.sharding == sharding:
+            return x
         host = np.asarray(jax.device_get(x))
         return jax.make_array_from_callback(
-            host.shape, NamedSharding(mesh, pspec), lambda idx: host[idx])
+            host.shape, sharding, lambda idx: host[idx])
 
     return jax.tree_util.tree_map(leaf, tree)
 

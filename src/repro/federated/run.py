@@ -43,7 +43,8 @@ scenario matrix (participation × stragglers × compression × DP from
 
 ``--devices N`` forces N XLA host devices (as ``launch/comm.py`` does) so
 the ``silo`` mesh axis actually spans devices and
-``Server.compiled_collective_bytes`` reports real collective traffic.
+``Server.compiled_collective_bytes`` reports real collective traffic. It
+is refused on an accelerator backend, where ``--mesh`` pins the devices.
 
 Execution topology is spec state (``spec.runtime``), set here with:
 
@@ -427,7 +428,8 @@ def _resume(args) -> int:
 
 def main(argv=None) -> int:
     """Run the requested spec(s) and assert the §3.2 byte ordering."""
-    args = build_parser().parse_args(argv)
+    parser = build_parser()
+    args = parser.parse_args(argv)
     if args.list_models:
         width = max(len(n) for n, _ in list_models())
         for name, desc in list_models():
@@ -446,6 +448,17 @@ def main(argv=None) -> int:
 
         distributed.initialize(args.coordinator, args.num_processes,
                                args.process_id)
+    import jax
+
+    from repro import compile_cache
+
+    if args.devices and jax.default_backend() != "cpu":
+        # The flag only multiplies CPU host devices; on an accelerator
+        # the mesh would silently span fewer devices than asked for.
+        parser.error(f"--devices forces CPU host devices, but the backend "
+                     f"is {jax.default_backend()!r}; pin the accelerator "
+                     f"mesh with --mesh instead")
+    compile_cache.enable()
     if args.resume:
         return _resume(args)
 

@@ -64,7 +64,7 @@ from typing import Any, Callable, Dict, Optional, Sequence, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro import debug
@@ -99,6 +99,11 @@ __all__ = [
 ]
 
 PyTree = Any
+
+# State groups stacked along the silo axis (sharded over ``silo``); the
+# rest of the round state — θ, η_G, the server optimizer — replicates.
+SILO_STATE = ("eta_L", "opt_local", "strategy")
+SERVER_STATE = ("theta", "eta_G", "opt_server")
 
 
 def stack_silos(datas: Sequence[PyTree]) -> PyTree:
@@ -419,21 +424,7 @@ class Server:
             "strategy": {},
         }
         self.state["strategy"] = self._strategy.init_silo_state(self)
-        if self.n_processes > 1:
-            # Every process computed identical host values (pure
-            # functions of the spec); turn them into global arrays so
-            # the jitted round accepts them — silo-sharded leaves cost
-            # each host only its own rows.
-            from repro.federated import distributed
-
-            self.data = distributed.globalize(self.data, self.mesh,
-                                              P("silo"))
-            for k in ("eta_L", "opt_local", "strategy"):
-                self.state[k] = distributed.globalize(
-                    self.state[k], self.mesh, P("silo"))
-            for k in ("theta", "eta_G", "opt_server"):
-                self.state[k] = distributed.globalize(
-                    self.state[k], self.mesh, P())
+        self.place()
         self.comm = CommMeter()
         # Shared across structurally-identical Servers (resume!) when the
         # builder hands in a token; private otherwise. See graph_cache.
@@ -487,12 +478,41 @@ class Server:
             self.state.get("strategy", {})
         ):
             self.state["strategy"] = strat.init_silo_state(self)
-            if self.n_processes > 1:
-                from repro.federated import distributed
-
-                self.state["strategy"] = distributed.globalize(
-                    self.state["strategy"], self.mesh, P("silo"))
+            self.place()
         self.state.setdefault("strategy", {})
+
+    # -- placement -----------------------------------------------------------
+
+    def _shardings(self):
+        """(state, data) shardings of the compiled round's inputs."""
+        rep = NamedSharding(self.mesh, P())
+        silo = NamedSharding(self.mesh, P("silo"))
+        state = {k: rep for k in SERVER_STATE}
+        state.update({k: silo for k in SILO_STATE})
+        return state, silo
+
+    def place(self) -> None:
+        """Commit data and state to the compiled round's input shardings.
+
+        Silo-stacked trees (data, η_L, local optimizer, strategy state)
+        shard over ``silo``; the server state replicates. Runs at build,
+        after :meth:`grow_silos`, and on resume: a round fed
+        default-device inputs would trace a second graph once its own
+        mesh-sharded outputs come back, and on several chips would copy
+        every silo's data from the first chip each round. Leaves already
+        placed are left as they are; a multi-process run globalizes
+        host values (identical on every process) instead.
+        """
+        state_sh, data_sh = self._shardings()
+        if self.n_processes > 1:
+            from repro.federated import distributed
+
+            def put(tree, sharding):
+                return distributed.globalize(tree, self.mesh, sharding.spec)
+        else:
+            put = jax.device_put
+        self.data = put(self.data, data_sh)
+        self.state = {k: put(v, state_sh[k]) for k, v in self.state.items()}
 
     # -- silo-axis padding ---------------------------------------------------
 
@@ -603,6 +623,7 @@ class Server:
             fresh = self._strategy.init_silo_state(self)
             self.state["strategy"] = jax.tree_util.tree_map(
                 lambda f, o: f.at[:old_J].set(o[:old_J]), fresh, old_strat)
+        self.place()
 
     # -- model-axis wire sharding -------------------------------------------
     #
@@ -730,8 +751,6 @@ class Server:
 
         compiled = self._lower(algorithm, local_steps).compile()
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):  # jax < 0.5 wraps it per-program
-            ca = ca[0] if ca else {}
         return {
             "flops": float(ca.get("flops", 0.0)),
             "bytes_accessed": float(ca.get("bytes accessed", 0.0)),
@@ -789,7 +808,7 @@ class Server:
                     f"strategy {strat.name!r} has unknown cadence "
                     f"{strat.cadence!r} (step/round)"
                 )
-            sharded = shard_map(
+            sharded = jax.shard_map(
                 body,
                 mesh=self.mesh,
                 in_specs=(
@@ -808,7 +827,7 @@ class Server:
                 out_specs=(
                     P(), P(), P(), P("silo"), P("silo"), P("silo"), P()
                 ),
-                check_rep=False,
+                check_vma=False,
             )
 
             # Mesh shape and J_pad ride the tag (a topology change or a
@@ -839,7 +858,16 @@ class Server:
                 }
                 return new_state, {"elbo": elbos}
 
-            self._round_fns[key] = jax.jit(round_fn)
+            # Explicit shardings: the outputs come back exactly as the
+            # inputs went in (see ``place``), so round r+1 reuses round
+            # r's compiled graph. Control inputs are small and replicate.
+            state_sh, data_sh = self._shardings()
+            rep = NamedSharding(self.mesh, P())
+            self._round_fns[key] = jax.jit(
+                round_fn,
+                in_shardings=(state_sh, data_sh, rep, rep, rep, rep),
+                out_shardings=(state_sh, {"elbo": rep}),
+            )
         return self._round_fns[key]
 
     def _ctx(self, K: int, wire) -> StrategyContext:

@@ -304,6 +304,9 @@ def main(argv: Optional[List[str]] = None) -> int:
                     help="query seed (same seed -> bit-identical answers)")
     args = ap.parse_args(argv)
 
+    from repro import compile_cache
+
+    compile_cache.enable()
     post = Posterior.from_checkpoint(args.ckpt_dir, step=args.step)
     out: Dict[str, Any] = {
         "round": post.round,

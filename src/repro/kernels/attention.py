@@ -21,6 +21,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -164,10 +165,5 @@ def flash_attention_bhsd(
 
 
 def pl_scratch(shape):
-    """VMEM f32 scratch (TPU: pltpu.VMEM; interpret mode: plain MemoryRef)."""
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        return pltpu.VMEM(shape, jnp.float32)
-    except Exception:  # pragma: no cover
-        return pl.MemoryRef(shape, jnp.float32)
+    """VMEM f32 scratch (interpret mode emulates the TPU memory space)."""
+    return pltpu.VMEM(shape, jnp.float32)

@@ -37,17 +37,26 @@ def _gla_kernel(q_ref, k_ref, v_ref, a_ref, o_ref, state_ref, *, nc: int):
     q = q_ref[0, 0].astype(jnp.float32)  # (C, dk)
     k = k_ref[0, 0].astype(jnp.float32)  # (C, dk)
     v = v_ref[0, 0].astype(jnp.float32)  # (C, dv)
-    a = a_ref[0, 0].astype(jnp.float32)  # (C,)
+    a = a_ref[0, 0].astype(jnp.float32)  # (1, C) lane-dense row
     C = q.shape[0]
 
-    cum = jnp.cumsum(a)  # (C,) L_i = sum_{r<=i} a_r
-    total = cum[-1]
-    # Within-chunk decay matrix, masked BEFORE exp (no inf * 0).
-    diff = cum[:, None] - cum[None, :]
     tri = (
         jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
         >= jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
     )
+    # L_i = sum_{r<=i} a_r as a column and as a row: two small matmuls
+    # against the lower-triangular ones matrix (Mosaic has no cumsum).
+    trif = tri.astype(jnp.float32)
+    exact = jax.lax.Precision.HIGHEST
+    cum = jax.lax.dot_general(  # (C, 1)
+        trif, a, (((1,), (1,)), ((), ())), precision=exact,
+        preferred_element_type=jnp.float32)
+    cum_row = jax.lax.dot_general(  # (1, C)
+        a, trif, (((1,), (1,)), ((), ())), precision=exact,
+        preferred_element_type=jnp.float32)
+    total = jnp.sum(a, axis=1, keepdims=True)  # (1, 1)
+    # Within-chunk decay matrix, masked BEFORE exp (no inf * 0).
+    diff = cum - cum_row
     D = jnp.exp(jnp.where(tri, diff, -jnp.inf))
 
     scores = jax.lax.dot_general(
@@ -57,14 +66,14 @@ def _gla_kernel(q_ref, k_ref, v_ref, a_ref, o_ref, state_ref, *, nc: int):
         scores, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
     # Cross-chunk: contribution of the state entering this chunk.
-    q_dec = q * jnp.exp(cum)[:, None]
+    q_dec = q * jnp.exp(cum)
     y = y + jax.lax.dot_general(
         q_dec, state_ref[...], (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
     o_ref[0, 0, ...] = y.astype(o_ref.dtype)
 
-    k_dec = k * jnp.exp(total - cum)[:, None]
+    k_dec = k * jnp.exp(total - cum)
     state_ref[...] = state_ref[...] * jnp.exp(total) + jax.lax.dot_general(
         k_dec, v, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
@@ -84,7 +93,8 @@ def gla_bhsd(
     Shapes: ``q``/``k`` are (B, H, S, dk), ``v`` is (B, H, S, dv),
     ``log_a`` is (B, H, S) per-step log decay (must be ≤ 0 for a stable
     recurrence); returns (B, H, S, dv) in ``q.dtype``. S must be a
-    multiple of ``chunk`` — ``ops.gla`` pads with identity steps
+    multiple of ``chunk``, and a compiled (TPU) ``chunk`` a multiple of
+    128 or all of S — ``ops.gla`` pads with identity steps
     (log_a = 0, k/v = 0, which neither read nor write the state). Inputs
     may be bf16/f32; the (dk, dv) recurrent state and all matmuls run in
     f32 VMEM scratch. The chunk axis of the grid is sequential, so the
@@ -102,10 +112,12 @@ def gla_bhsd(
             pl.BlockSpec((1, 1, chunk, dk), lambda b, h, c: (b, h, c, 0)),
             pl.BlockSpec((1, 1, chunk, dk), lambda b, h, c: (b, h, c, 0)),
             pl.BlockSpec((1, 1, chunk, dv), lambda b, h, c: (b, h, c, 0)),
-            pl.BlockSpec((1, 1, chunk), lambda b, h, c: (b, h, c)),
+            # log_a rides as (B, H, 1, S): its block's last two dims are
+            # (1, chunk) — full and lane-aligned — as the TPU tiling needs.
+            pl.BlockSpec((1, 1, 1, chunk), lambda b, h, c: (b, h, 0, c)),
         ],
         out_specs=pl.BlockSpec((1, 1, chunk, dv), lambda b, h, c: (b, h, c, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, S, dv), q.dtype),
         scratch_shapes=[pl_scratch((dk, dv))],
         interpret=interpret,
-    )(q, k, v, log_a)
+    )(q, k, v, log_a.reshape(B, H, 1, S))

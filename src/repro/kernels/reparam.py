@@ -7,9 +7,11 @@ Every SFVI iteration evaluates, for millions of latent components,
 
 Unfused, that is 4 HBM round-trips over (mu, log_sigma, eps) plus a
 separate reduction pass. The kernel reads each operand once, emits z, and
-reduces the per-element logq terms to ONE partial per grid block in the
-same pass — the classic fuse-map-with-reduction pattern; the caller sums
-the (n_blocks,) partials (a trivially small array).
+reduces the per-element logq terms to ONE (8, 128) partial tile per grid
+block in the same pass — the classic fuse-map-with-reduction pattern; the
+caller sums the partials (a trivially small array). Latents ride as
+lane-dense (rows, 128) tiles with rows a multiple of 8, the TPU's f32
+tiling.
 
 This is the SFVI-specific hot-spot kernel: it is memory-bound and sits on
 the critical path of every silo's local step (paper Algorithm 1 lines
@@ -23,8 +25,11 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _HALF_LOG_2PI = 0.5 * math.log(2.0 * math.pi)
+_LANES = 128
+_SUBLANES = 8
 
 
 def _reparam_kernel(mu_ref, ls_ref, eps_ref, z_ref, lq_ref):
@@ -32,8 +37,8 @@ def _reparam_kernel(mu_ref, ls_ref, eps_ref, z_ref, lq_ref):
     ls = ls_ref[...].astype(jnp.float32)
     eps = eps_ref[...].astype(jnp.float32)
     z_ref[...] = (mu + jnp.exp(ls) * eps).astype(z_ref.dtype)
-    lq = -0.5 * eps * eps - ls - _HALF_LOG_2PI
-    lq_ref[0, 0] = jnp.sum(lq)
+    lq = -0.5 * eps * eps - ls - _HALF_LOG_2PI  # (rows, 128)
+    lq_ref[0] = jnp.sum(lq.reshape(-1, _SUBLANES, _LANES), axis=0)
 
 
 def _reparam_bwd_kernel(ls_ref, eps_ref, dz_ref, dlq_ref, dmu_ref, dls_ref,
@@ -47,7 +52,7 @@ def _reparam_bwd_kernel(ls_ref, eps_ref, dz_ref, dlq_ref, dmu_ref, dls_ref,
     ls = ls_ref[...].astype(jnp.float32)
     eps = eps_ref[...].astype(jnp.float32)
     dz = dz_ref[...].astype(jnp.float32)
-    dlq = dlq_ref[0, 0]
+    dlq = dlq_ref[0, 0]  # scalar cotangent of logq (SMEM)
     sig = jnp.exp(ls)
     dmu_ref[...] = dz.astype(dmu_ref.dtype)
     dls_ref[...] = (dz * sig * eps - dlq).astype(dls_ref.dtype)
@@ -66,9 +71,10 @@ def reparam_stl(
     Shapes: ``mu``, ``log_sigma``, ``eps`` are (N,) flattened latent
     vectors of matching length; returns ``(z, logq)`` with z (N,) in
     ``mu.dtype`` and logq a f32 scalar (the block partials are reduced
-    in f32 regardless of input dtype). Pads internally to a ``block``
-    multiple; the pad contributes 0 to logq via eps=0, log_sigma=0
-    padding and the −0.5·log 2π constant is corrected analytically.
+    in f32 regardless of input dtype). ``block`` is the elements per grid
+    tile, rounded to whole (8, 128) tiles. Pads internally to a tile
+    multiple; the pad contributes exactly 0 to logq (eps=0 and
+    log_sigma=−0.5·log 2π padding).
     Differentiable via a fused Pallas backward kernel (custom VJP — the
     STL stop-gradient is structural: logq's pathwise term never
     references mu/log_sigma). Reference implementation:
@@ -83,41 +89,52 @@ def _reparam_stl_vjp(mu, log_sigma, eps, block, interpret):
     return z, lq
 
 
-def _blocked(x, block):
+def _tile_rows(N: int, block: int) -> int:
+    """Rows of one (rows, 128) grid tile: ~``block`` elements, a multiple
+    of 8, and no more than the padded latent needs."""
+    rows = max(block // _LANES // _SUBLANES, 1) * _SUBLANES
+    need = -(-max(N, 1) // (_LANES * _SUBLANES)) * _SUBLANES
+    return min(rows, need)
+
+
+def _blocked(x, rows, fill=0.0):
+    """(N,) -> ``fill``-padded (n_blocks * rows, 128) lane-dense tiles."""
     (N,) = x.shape
-    pad = (-N) % block
+    pad = (-N) % (rows * _LANES)
     if pad:
-        x = jnp.pad(x, (0, pad))
-    return x.reshape(-1, block), pad
+        x = jnp.pad(x, (0, pad), constant_values=fill)
+    return x.reshape(-1, _LANES)
+
+
+def _tile_spec(rows):
+    return pl.BlockSpec((rows, _LANES), lambda i: (i, 0))
 
 
 def _reparam_fwd_impl(mu, log_sigma, eps, block, interpret):
     (N,) = mu.shape
-    block = min(block, max(N, 1))
-    mu2, pad = _blocked(mu, block)
-    ls2, _ = _blocked(log_sigma, block)
-    eps2, _ = _blocked(eps, block)
-    n_blocks = mu2.shape[0]
+    rows = _tile_rows(N, block)
+    mu2 = _blocked(mu, rows)
+    # Pad log_sigma with -½log 2π (and eps with 0): each pad element's
+    # logq term is then exactly 0, so the pad never enters the sum.
+    ls2 = _blocked(log_sigma, rows, fill=-_HALF_LOG_2PI)
+    eps2 = _blocked(eps, rows)
+    n_blocks = mu2.shape[0] // rows
     z, lq = pl.pallas_call(
         _reparam_kernel,
         grid=(n_blocks,),
-        in_specs=[
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-        ],
+        in_specs=[_tile_spec(rows)] * 3,
         out_specs=[
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            _tile_spec(rows),
+            pl.BlockSpec((1, _SUBLANES, _LANES), lambda i: (i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n_blocks, block), mu.dtype),
-            jax.ShapeDtypeStruct((n_blocks, 1), jnp.float32),
+            jax.ShapeDtypeStruct(mu2.shape, mu.dtype),
+            jax.ShapeDtypeStruct((n_blocks, _SUBLANES, _LANES), jnp.float32),
         ],
         interpret=interpret,
     )(mu2, ls2, eps2)
-    logq = jnp.sum(lq) + pad * _HALF_LOG_2PI  # remove pad's constant terms
-    return z.reshape(-1)[:N], logq, (log_sigma, eps, block, N)
+    logq = jnp.sum(lq)
+    return z.reshape(-1)[:N], logq, (log_sigma, eps, rows, N)
 
 
 def _reparam_fwd(mu, log_sigma, eps, block, interpret):
@@ -126,36 +143,25 @@ def _reparam_fwd(mu, log_sigma, eps, block, interpret):
 
 
 def _reparam_bwd(block_arg, interpret, res, cts):
-    log_sigma, eps, block, N = res
+    log_sigma, eps, rows, N = res
     dz, dlq = cts
-    ls2, pad = _blocked(log_sigma, block)
-    eps2, _ = _blocked(eps, block)
-    dz2, _ = _blocked(dz, block)
-    n_blocks = ls2.shape[0]
-    dlq_blocks = jnp.broadcast_to(
-        jnp.asarray(dlq, jnp.float32).reshape(1, 1), (n_blocks, 1)
-    )
+    ls2 = _blocked(log_sigma, rows)
+    eps2 = _blocked(eps, rows)
+    dz2 = _blocked(dz, rows)
+    n_blocks = ls2.shape[0] // rows
     dmu, dls, deps = pl.pallas_call(
         _reparam_bwd_kernel,
         grid=(n_blocks,),
-        in_specs=[
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-        ],
+        in_specs=[_tile_spec(rows)] * 3
+        + [pl.BlockSpec(memory_space=pltpu.SMEM)],
+        out_specs=[_tile_spec(rows)] * 3,
         out_shape=[
-            jax.ShapeDtypeStruct((n_blocks, block), log_sigma.dtype),
-            jax.ShapeDtypeStruct((n_blocks, block), log_sigma.dtype),
-            jax.ShapeDtypeStruct((n_blocks, block), eps.dtype),
+            jax.ShapeDtypeStruct(ls2.shape, log_sigma.dtype),
+            jax.ShapeDtypeStruct(ls2.shape, log_sigma.dtype),
+            jax.ShapeDtypeStruct(ls2.shape, eps.dtype),
         ],
         interpret=interpret,
-    )(ls2, eps2, dz2, dlq_blocks)
+    )(ls2, eps2, dz2, jnp.asarray(dlq, jnp.float32).reshape(1, 1))
     unpad = lambda a: a.reshape(-1)[:N]  # noqa: E731
     return unpad(dmu), unpad(dls), unpad(deps)
 

@@ -15,7 +15,7 @@ import sys
 import jax
 
 from repro.configs import INPUT_SHAPES, get_config
-from repro.launch.mesh import make_production_mesh, use_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.launch.roofline import collective_bytes
 from repro.launch.specs import build_avg_lowering, build_lowering
 
@@ -25,7 +25,7 @@ def measure(arch: str, shape_name: str = "train_4k") -> dict:
     shape = INPUT_SHAPES[shape_name]
     mesh = make_production_mesh()
     out = {"arch": arch, "shape": shape_name}
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         fn, args = build_lowering(cfg, shape, mesh)
         c = jax.jit(fn).lower(*args).compile()
         out["sfvi"] = sum(collective_bytes(c.as_text()).values())

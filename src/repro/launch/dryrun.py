@@ -27,7 +27,7 @@ import jax
 
 from repro.configs import ARCH_NAMES, INPUT_SHAPES, get_config
 from repro.launch import roofline as R
-from repro.launch.mesh import make_production_mesh, use_mesh
+from repro.launch.mesh import PRODUCTION_DEVICE_KIND, make_production_mesh
 from repro.launch.specs import build_lowering
 
 
@@ -61,7 +61,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, verbose: bool = True,
     mesh_name = "multi_pod" if multi_pod else "single_pod"
     chips = mesh.size
     t0 = time.time()
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         # --- production compile: proves lowering; memory analysis ---------
         fn, args = build_lowering(cfg, shape, mesh)
         lowered = jax.jit(fn).lower(*args)
@@ -75,6 +75,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, verbose: bool = True,
         roof = R.analyze(
             compiled, arch, shape_name, mesh_name, chips,
             model_flops=R.model_flops(cfg, shape),
+            device_kind=PRODUCTION_DEVICE_KIND,
         )
         # --- analysis compiles: scan-aware flops/bytes/collectives --------
         if analyze:

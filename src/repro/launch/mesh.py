@@ -16,8 +16,7 @@ Two mesh families share this module:
 
 ``MeshSpec`` is the JSON-native description the experiment spec carries
 (:class:`repro.federated.api.ExperimentSpec` — ``spec.runtime.mesh``);
-``build_mesh`` is the only construction path, so every version shim
-(``AxisType``, ``jax.set_mesh``) lives here exactly once.
+``build_mesh`` is the only construction path for the federated mesh.
 
 Everything is a function — importing this module never touches jax
 device state (device count is locked at first jax init).
@@ -30,10 +29,36 @@ from typing import Any, Dict, Optional
 import jax
 import numpy as np
 
-# TPU v5e hardware constants (per chip) for the roofline model.
-PEAK_FLOPS_BF16 = 197e12  # FLOP/s
-HBM_BW = 819e9  # bytes/s
-ICI_BW = 50e9  # bytes/s per link
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks for the roofline model."""
+
+    flops_bf16: float  # FLOP/s
+    hbm_bw: float  # bytes/s
+    ici_bw: float  # bytes/s per link
+
+
+# Keyed by ``jax.Device.device_kind``. TPU v5e: Google Cloud documentation,
+# "TPU v5e" — 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of
+# chip-to-chip interconnect over 4 links.
+CHIP_PEAKS: Dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(flops_bf16=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+# The chip the production mesh (``make_production_mesh``) describes.
+PRODUCTION_DEVICE_KIND = "TPU v5 lite"
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """Peaks of one chip of ``device_kind``; an unlisted kind is an error."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r} "
+            f"(known: {sorted(CHIP_PEAKS)})") from None
+
 
 
 @dataclasses.dataclass(frozen=True)
@@ -89,26 +114,10 @@ class MeshSpec:
 
 
 def _mk_mesh(devices, axes):
-    """The one construction shim: a Mesh with Auto axis types everywhere.
-
-    jax < 0.5 has no ``sharding.AxisType``; Auto is the default there,
-    so the kwarg is only passed when it exists.
-    """
-    devices = np.asarray(devices)
-    if hasattr(jax.sharding, "AxisType"):  # repro-lint: allow[R6] — jax cross-version feature shim, not a protocol probe
-        return jax.sharding.Mesh(
-            devices, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.sharding.Mesh(devices, axes)
-
-
-def use_mesh(mesh):
-    """Version-portable mesh context: ``jax.set_mesh`` where it exists
-    (jax >= 0.6), else the ``Mesh`` object itself (a context manager that
-    sets the physical mesh on 0.4.x)."""
-    if hasattr(jax, "set_mesh"):  # repro-lint: allow[R6] — jax cross-version feature shim, not a protocol probe
-        return jax.set_mesh(mesh)
-    return mesh
+    """A Mesh with Auto axis types everywhere (GSPMD picks the layouts)."""
+    return jax.sharding.Mesh(
+        np.asarray(devices), axes,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def build_mesh(spec: Optional[MeshSpec] = None, *,
@@ -161,7 +170,10 @@ def build_mesh(spec: Optional[MeshSpec] = None, *,
 
 
 def make_production_mesh(*, multi_pod: bool = False):
-    """Production TPU mesh: (data=16, model=16), ×2 pods when asked."""
+    """Production TPU mesh: (data=16, model=16), ×2 pods when asked.
+
+    Its chips are ``PRODUCTION_DEVICE_KIND``, whatever devices stand in
+    for them in a dry run."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     return _mk_mesh(np.asarray(jax.devices()[: int(np.prod(shape))])

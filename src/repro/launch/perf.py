@@ -21,7 +21,7 @@ import jax
 
 from repro.configs import INPUT_SHAPES, get_config
 from repro.launch import roofline as R
-from repro.launch.mesh import make_production_mesh, use_mesh
+from repro.launch.mesh import PRODUCTION_DEVICE_KIND, make_production_mesh
 from repro.launch.specs import build_lowering
 from repro.models.backbone.config import PerfConfig
 
@@ -45,11 +45,12 @@ def measure(arch: str, shape_name: str, levers: list) -> dict:
     shape = INPUT_SHAPES[shape_name]
     mesh = make_production_mesh()
     t0 = time.time()
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         fn, args = build_lowering(cfg, shape, mesh)
         compiled = jax.jit(fn).lower(*args).compile()
         roof = R.analyze(compiled, arch, shape_name, "single_pod", mesh.size,
-                         model_flops=R.model_flops(cfg, shape))
+                         model_flops=R.model_flops(cfg, shape),
+                         device_kind=PRODUCTION_DEVICE_KIND)
         n_units = cfg.num_layers // R._unit_period(cfg)
         ms = []
         for k in (1, 2):

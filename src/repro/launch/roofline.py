@@ -1,8 +1,11 @@
 """Roofline-term extraction from a compiled (dry-run) artifact.
 
-    compute term    = HLO_FLOPs      / (chips * PEAK_FLOPS_BF16)
-    memory term     = HLO_bytes      / (chips * HBM_BW)
-    collective term = collective_bytes / (chips * ICI_BW)
+    compute term    = HLO_FLOPs      / (chips * peak FLOP/s)
+    memory term     = HLO_bytes      / (chips * HBM bytes/s)
+    collective term = collective_bytes / (chips * ICI bytes/s)
+
+with the peaks of the target chip (``launch.mesh.chip_peaks``, keyed by
+``device_kind``).
 
 ``cost_analysis()`` reports per-partition (per-device) FLOPs/bytes for an
 SPMD executable, so the per-chip terms divide by peak directly; the
@@ -19,7 +22,7 @@ import json
 import re
 from typing import Dict, Optional
 
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import chip_peaks
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -78,18 +81,19 @@ class Roofline:
     coll_breakdown: Dict[str, float]
     peak_bytes_per_chip: float  # memory_analysis: peak HBM
     model_flops: float  # 6*N*D (active) — analytic useful work, GLOBAL
+    device_kind: str  # the target chip, keys launch.mesh.CHIP_PEAKS
 
     @property
     def t_compute(self) -> float:
-        return self.flops_per_chip / PEAK_FLOPS_BF16
+        return self.flops_per_chip / chip_peaks(self.device_kind).flops_bf16
 
     @property
     def t_memory(self) -> float:
-        return self.bytes_per_chip / HBM_BW
+        return self.bytes_per_chip / chip_peaks(self.device_kind).hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return self.coll_bytes_per_chip / ICI_BW
+        return self.coll_bytes_per_chip / chip_peaks(self.device_kind).ici_bw
 
     @property
     def bottleneck(self) -> str:
@@ -108,7 +112,7 @@ class Roofline:
     def to_dict(self) -> dict:
         return {
             "arch": self.arch, "shape": self.shape, "mesh": self.mesh,
-            "chips": self.chips,
+            "chips": self.chips, "device_kind": self.device_kind,
             "flops_per_chip": self.flops_per_chip,
             "bytes_per_chip": self.bytes_per_chip,
             "coll_bytes_per_chip": self.coll_bytes_per_chip,
@@ -124,10 +128,8 @@ class Roofline:
 
 
 def analyze(compiled, arch: str, shape: str, mesh_name: str, chips: int,
-            model_flops: float) -> Roofline:
+            model_flops: float, device_kind: str) -> Roofline:
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # jax < 0.5 wraps it per-program
-        ca = ca[0] if ca else {}
     flops = float(ca.get("flops", 0.0))
     byts = float(ca.get("bytes accessed", 0.0))
     coll = collective_bytes(compiled.as_text())
@@ -145,7 +147,7 @@ def analyze(compiled, arch: str, shape: str, mesh_name: str, chips: int,
         flops_per_chip=flops, bytes_per_chip=byts,
         coll_bytes_per_chip=sum(coll.values()),
         coll_breakdown=coll, peak_bytes_per_chip=peak,
-        model_flops=model_flops,
+        model_flops=model_flops, device_kind=device_kind,
     )
 
 
@@ -229,8 +231,6 @@ def analysis_variant(cfg, k_units: int):
 
 def _extract(compiled) -> Dict[str, float]:
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # jax < 0.5 wraps it per-program
-        ca = ca[0] if ca else {}
     coll = collective_bytes(compiled.as_text())
     return {
         "flops": float(ca.get("flops", 0.0)),

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.configs import get_config
 from repro.data.synthetic import make_token_stream
 from repro.checkpoint.io import CheckpointManager
@@ -28,7 +29,7 @@ from repro.federated import (CommMeter, ExperimentSpec, MeshSpec, ModelSpec,
                              NoCompression, OptimizerSpec, RuntimeSpec,
                              Scenario, run_rounds)
 from repro.launch import steps as S
-from repro.launch.mesh import build_mesh, use_mesh
+from repro.launch.mesh import build_mesh
 from repro.models.backbone import transformer as T
 
 
@@ -79,6 +80,7 @@ def main(argv=None):
                          "provenance record: the same scenario fields the "
                          "compiled runtime would be built from.")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     # Declarative record of the run: the SPMD cadence expressed in the
     # same (scenario, optimizer, seed) vocabulary as repro.federated.api.
@@ -105,7 +107,7 @@ def main(argv=None):
     # The declared topology is also the executed one: the jitted step
     # lowers against the spec's mesh (one factory, launch.mesh.build_mesh,
     # for the CLI, api.build and the benchmarks alike).
-    mesh_ctx = (use_mesh(build_mesh(spec.runtime.mesh,
+    mesh_ctx = (jax.set_mesh(build_mesh(spec.runtime.mesh,
                                     num_silos=args.silos))
                 if args.mesh else contextlib.nullcontext())
 

@@ -85,15 +85,13 @@ def _pad_heads(cfg, q, kf, vf):
     q = jnp.pad(q, pad)
     kf = jnp.pad(kf, pad)
     vf = jnp.pad(vf, pad)
-    try:  # hint GSPMD to shard the padded head axis (no-op without a mesh)
-        from jax.sharding import PartitionSpec as _P
-
-        spec = _P(None, None, "model", None)
+    # Hint GSPMD to shard the padded head axis over the active mesh's
+    # model axis; without such a mesh there is nothing to shard over.
+    if "model" in jax.sharding.get_abstract_mesh().axis_names:
+        spec = jax.sharding.PartitionSpec(None, None, "model", None)
         q = jax.lax.with_sharding_constraint(q, spec)
         kf = jax.lax.with_sharding_constraint(kf, spec)
         vf = jax.lax.with_sharding_constraint(vf, spec)
-    except Exception:
-        pass
     return q, kf, vf, H
 
 

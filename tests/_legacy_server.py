@@ -22,7 +22,6 @@ from typing import Any, Callable, Dict, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.barycenter import family_barycenter
@@ -449,8 +448,6 @@ class LegacyServer:
             self.state, self.data, jax.random.PRNGKey(0), ones, ones
         ).compile()
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):  # jax < 0.5 wraps it per-program
-            ca = ca[0] if ca else {}
         return {
             "flops": float(ca.get("flops", 0.0)),
             "bytes_accessed": float(ca.get("bytes accessed", 0.0)),
@@ -483,7 +480,7 @@ class LegacyServer:
                 body = self._avg_body(local_steps)
             else:
                 raise ValueError(f"unknown algorithm {algorithm!r}")
-            sharded = shard_map(
+            sharded = jax.shard_map(
                 body,
                 mesh=self.mesh,
                 in_specs=(
@@ -499,7 +496,7 @@ class LegacyServer:
                     P(), P(), P(),  # full mask, full weights, round key
                 ),
                 out_specs=(P(), P(), P(), P("silo"), P("silo"), P()),
-                check_rep=False,
+                check_vma=False,
             )
 
             def round_fn(state, data, round_key, mask, weights):

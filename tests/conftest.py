@@ -8,24 +8,3 @@ import pytest
 @pytest.fixture(scope="session")
 def rng_key():
     return jax.random.PRNGKey(0)
-
-
-def pytest_collection_modifyitems(config, items):
-    """Skip ONLY the genuinely compile-impossible cases off-TPU.
-
-    Kernel suites run everywhere via ``interpret=True``; the
-    ``tpu_only`` marker is reserved for tests of the compiled Mosaic
-    lowering itself, which has no CPU equivalent. Never skip a whole
-    module for a missing accelerator (or a missing optional dep — use a
-    seeded fallback sweep instead, see test_kernels.py).
-    """
-    if not any(item.get_closest_marker("tpu_only") for item in items):
-        return
-    if jax.default_backend() == "tpu":
-        return
-    skip = pytest.mark.skip(
-        reason="needs a TPU backend (compiled Mosaic path); CPU CI runs "
-               "the interpret-mode equivalents")
-    for item in items:
-        if item.get_closest_marker("tpu_only"):
-            item.add_marker(skip)

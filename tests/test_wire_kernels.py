@@ -320,21 +320,3 @@ else:
     @pytest.mark.parametrize("J,P,trim_i,pattern_i", _CASES)
     def test_wire_kernels_property(J, P, trim_i, pattern_i):
         _check_random_case(J, P, trim_i, pattern_i)
-
-
-@pytest.mark.tpu_only
-def test_wire_kernels_compile_to_mosaic():
-    """The compiled (non-interpret) lowering agrees with interpret mode.
-
-    Only meaningful on a real TPU backend — interpret mode IS the CPU
-    execution path, so there is nothing to cross-check here off-TPU.
-    (Note the Mosaic path would also need a hardware PRNG for the noise
-    stage; this exercises the noiseless kernels only.)
-    """
-    x = _mat((8, 256))
-    mask = _mask(8, "random")
-    a = ops.wire_upload(x, mask, clip_norm=0.5, quantize=True,
-                        interpret=False)
-    b = ops.wire_upload(x, mask, clip_norm=0.5, quantize=True,
-                        interpret=True)
-    _exact(a, b)
