@@ -24,6 +24,18 @@ Every query is deterministic in its ``seed`` — two replicas serving the
 same checkpoint return bit-identical answers, the serving-side analogue
 of the trainer's bit-exact resume contract.
 
+Each group is one dispatch of one jitted program: the serving key
+(``fold_in(PRNGKey(salt + seed), silo + 1)``) and the silo's row of the
+stacked ``η_L`` are taken inside it from a traced ``(seed, silo)``.
+Samplers compile per row bucket, the next power of two at or above the
+rows asked for, not per row count: a group of ``Σ n`` draws takes the
+first ``Σ n`` rows of its bucket. Draws are prefix-consistent (with
+JAX's default partitionable threefry, ``normal(k, (N,) + s)[:n]`` equals
+``normal(k, (n,) + s)``, and the families sample row by row), so the
+bucket changes no answer, and a total above every bucket served so far
+compiles one new power of two, once. ``predict`` averages over exactly
+``n`` draws, so its programs stay keyed on ``n`` and the shape of ``x``.
+
 CLI::
 
     python -m repro.federated.serve --ckpt-dir runs/demo --silo 0 --n 3
@@ -31,8 +43,9 @@ CLI::
     python -m repro.federated.serve --ckpt-dir runs/demo \
         --queries '[{"kind": "sample", "silo": 1, "n": 2}]'
 
-Latency/throughput numbers live in ``benchmarks/bench_serving.py``
-(the federated-posterior row).
+Latency under open-loop traffic is measured by the chip benchmark's
+``hier_bnn-serve-poisson-j64`` cell (``BENCHMARK.json``,
+``perfbench/drivers/serve.py``).
 """
 from __future__ import annotations
 
@@ -51,6 +64,39 @@ PyTree = Any
 # Fold-in salt separating the serving key stream from training's
 # round keys (fold_in(seed, round)) and the population/latency salts.
 _SERVE_SALT = 0x53E7
+
+
+def _bucket(n: int) -> int:
+    """Rows a group of ``n`` draws is served from: the next power of two."""
+    return 1 << (n - 1).bit_length()
+
+
+def _stream(seed: int, silo: int) -> np.ndarray:
+    """``[_SERVE_SALT + seed, silo]``, the traced argument that keys a
+    serving program.
+
+    ``PRNGKey`` of a Python int goes through int64 to JAX's default int
+    (int32 unless x64 is on), wrapping; the same cast here keeps the
+    in-program key equal to :meth:`Posterior._key`'s for every seed it
+    accepts, and raises the same ``OverflowError`` for the rest.
+    """
+    return np.array([_SERVE_SALT + seed, silo], np.int64).astype(
+        jax.dtypes.canonicalize_dtype(np.int64))
+
+
+def _stream_key(stream: jax.Array) -> jax.Array:
+    """The serving key of ``stream``, inside a program."""
+    # repro-lint: allow[R1] — serving key root: pure function of the query seed, disjoint from training streams
+    return jax.random.fold_in(jax.random.PRNGKey(stream[0]), stream[1] + 1)
+
+
+def _silo_row(eta_L: PyTree, silo: jax.Array) -> PyTree:
+    """Row ``silo`` of the stacked ``η_L``, inside a program."""
+    if eta_L is None:
+        return None
+    return jax.tree_util.tree_map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, silo, keepdims=False),
+        eta_L)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -98,8 +144,8 @@ class Posterior:
         self.experiment = experiment
         self.server = experiment.server
         self.problem = self.server.problem
-        # Sampling shapes are static per (kind, n, x-shape); memoize the
-        # jitted closures so a serving loop pays one trace per shape.
+        # One jitted program per (kind, row bucket) for the samplers and
+        # per (n, x-shape) for predict: a serving loop compiles a handful.
         self._compiled: Dict[tuple, Any] = {}
 
     @classmethod
@@ -123,10 +169,7 @@ class Posterior:
 
     def eta_row(self, silo: int) -> PyTree:
         """Silo ``silo``'s private ``η_{L_j}`` (row of the stacked axis)."""
-        if not 0 <= silo < self.server.J:
-            raise IndexError(
-                f"silo {silo} out of range: checkpoint serves "
-                f"{self.server.J} silos")
+        self._check_silo(silo)
         if not self.problem.model.has_local:
             return None
         return jax.tree_util.tree_map(
@@ -135,6 +178,9 @@ class Posterior:
     # -- sampling ------------------------------------------------------------
 
     def _key(self, seed: int, silo: int) -> jax.Array:
+        """The serving key of ``(seed, silo)``, derived eagerly: the
+        reference that every program's in-graph key
+        (:func:`_stream_key`) equals bit for bit."""
         with self._bridge():
             # silo + 1: fold_in data is uint32 and the global stream
             # uses silo = -1.
@@ -148,37 +194,37 @@ class Posterior:
 
         return debug.host_bridge()
 
-    def _sampler(self, n: int):
-        key = ("sample", n)
-        if key not in self._compiled:
-            prob = self.problem
+    def _local_state(self) -> PyTree:
+        """The stacked ``η_L`` a program takes its silo's row from."""
+        if not self.problem.model.has_local:
+            return None
+        return self.server.state["eta_L"]
 
-            def draw(eta_G, eta_L, k):
-                return prob.sample_posterior(eta_G, eta_L, k, num_samples=n)
+    def _program(self, kind: str, rows: int, x_shape: tuple = ()):
+        """The jitted program of one group: ``rows`` draws of ``kind``
+        keyed by the traced ``_stream(seed, silo)``, which also picks the
+        silo's row of the stacked ``η_L`` inside the program."""
+        ck = (kind, rows, x_shape)
+        if ck in self._compiled:
+            return self._compiled[ck]
+        prob = self.problem
 
-            self._compiled[key] = jax.jit(draw)
-        return self._compiled[key]
-
-    def _global_sampler(self, n: int):
-        key = ("global", n)
-        if key not in self._compiled:
-            prob = self.problem
-
-            def draw(eta_G, k):
-                return prob.sample_posterior(eta_G, None, k, num_samples=n)[0]
-
-            self._compiled[key] = jax.jit(draw)
-        return self._compiled[key]
-
-    def _predictor(self, n: int, x_shape: tuple):
-        key = ("predict", n, x_shape)
-        if key not in self._compiled:
-            prob = self.problem
+        if kind == "global_sample":
+            def run(eta_G, stream):
+                return prob.sample_posterior(eta_G, None, _stream_key(stream),
+                                             num_samples=rows)[0]
+        elif kind == "sample":
+            def run(eta_G, eta_L, stream):
+                return prob.sample_posterior(
+                    eta_G, _silo_row(eta_L, stream[1]), _stream_key(stream),
+                    num_samples=rows)
+        else:
             predict = prob.model.predict
 
-            def run(theta, eta_G, eta_L, x, k):
-                z_G, z_L = prob.sample_posterior(eta_G, eta_L, k,
-                                                 num_samples=n)
+            def run(theta, eta_G, eta_L, x, stream):
+                z_G, z_L = prob.sample_posterior(
+                    eta_G, _silo_row(eta_L, stream[1]), _stream_key(stream),
+                    num_samples=rows)
                 if z_L is None:
                     out = jax.vmap(lambda zg: predict(theta, zg, None, x))(z_G)
                 else:
@@ -186,13 +232,23 @@ class Posterior:
                         lambda zg, zl: predict(theta, zg, zl, x))(z_G, z_L)
                 return jnp.mean(out, axis=0)
 
-            self._compiled[key] = jax.jit(run)
-        return self._compiled[key]
+        self._compiled[ck] = jax.jit(run)
+        return self._compiled[ck]
+
+    def _check_silo(self, silo: int) -> None:
+        if not 0 <= silo < self.server.J:
+            raise IndexError(
+                f"silo {silo} out of range: checkpoint serves "
+                f"{self.server.J} silos")
 
     def global_sample(self, n: int = 1, seed: int = 0) -> jax.Array:
         """``n`` draws of ``Z_G`` from ``q_{η_G}`` — shape ``(n, d_G)``."""
-        fn = self._global_sampler(int(n))
-        return fn(self.server.state["eta_G"], self._key(seed, -1))
+        n = int(n)
+        rows = _bucket(n)
+        fn = self._program("global_sample", rows)
+        with self._bridge():
+            z = fn(self.server.state["eta_G"], _stream(seed, -1))
+        return z if rows == n else z[:n]
 
     def sample(self, silo: int, n: int = 1,
                seed: int = 0) -> Dict[str, Optional[jax.Array]]:
@@ -202,10 +258,16 @@ class Posterior:
         families draw ``Z_L | Z_G`` from the SAME ``Z_G`` realization
         returned, so the pair is a joint posterior draw.
         """
-        eta_L = self.eta_row(silo)
-        fn = self._sampler(int(n))
-        z_G, z_L = fn(self.server.state["eta_G"], eta_L,
-                      self._key(seed, silo))
+        self._check_silo(silo)
+        n = int(n)
+        rows = _bucket(n)
+        fn = self._program("sample", rows)
+        with self._bridge():
+            z_G, z_L = fn(self.server.state["eta_G"], self._local_state(),
+                          _stream(seed, silo))
+        if rows != n:
+            z_G = z_G[:n]
+            z_L = None if z_L is None else z_L[:n]
         return {"z_G": z_G, "z_L": z_L}
 
     def predict(self, silo: int, x, n: int = 8, seed: int = 0) -> jax.Array:
@@ -219,11 +281,14 @@ class Posterior:
             raise ValueError(
                 f"model {self.problem.model.name!r} has no predict hook; "
                 f"only sample/global_sample queries are servable")
-        eta_L = self.eta_row(silo)
+        self._check_silo(silo)
         x = jnp.asarray(x)
-        fn = self._predictor(int(n), tuple(x.shape))
-        return fn(self.server.state["theta"], self.server.state["eta_G"],
-                  eta_L, x, self._key(seed, silo))
+        # The mean is over exactly n draws: no row bucket here.
+        fn = self._program("predict", int(n), tuple(x.shape))
+        st = self.server.state
+        with self._bridge():
+            return fn(st["theta"], st["eta_G"], self._local_state(), x,
+                      _stream(seed, silo))
 
     # -- request batching ----------------------------------------------------
 
@@ -232,14 +297,14 @@ class Posterior:
         """Serve ``queries``, batching draws per (kind, silo) group.
 
         All ``sample``/``global_sample`` queries hitting the same silo
-        are served by ONE vectorized ``num_samples = Σ n`` call and the
-        per-query answers are contiguous slices of that batch, in
-        request order — the amortization that makes many small queries
-        as cheap as one big one. ``predict`` queries keep one call per
-        query (their ``x`` shapes differ), but still share the group's
-        compiled sampler. Answers are returned in request order; the
-        batching is invisible in the results (same draws as issuing the
-        grouped queries back-to-back with one shared key per group).
+        are served by ONE vectorized call that draws the bucket of
+        ``Σ n`` rows, and the per-query answers are contiguous slices of
+        its first ``Σ n`` rows, in request order — the amortization that
+        makes many small queries as cheap as one big one. ``predict``
+        queries keep one call per query (their ``x`` shapes differ).
+        Answers are returned in request order; the batching is invisible
+        in the results (same draws as issuing the grouped queries
+        back-to-back with one shared key per group).
         """
         groups: Dict[Tuple[str, int], List[int]] = {}
         for i, q in enumerate(queries):
@@ -252,12 +317,13 @@ class Posterior:
                     q = queries[i]
                     answers[i] = self.predict(silo, q.x, n=q.n, seed=seed)
                 continue
-            total = sum(queries[i].n for i in idxs)
+            # The group's draws are the first Σ n rows of its bucket.
+            rows = _bucket(sum(queries[i].n for i in idxs))
             if kind == "global_sample":
-                z = self.global_sample(total, seed=seed)
+                z = self.global_sample(rows, seed=seed)
                 batch = {"z_G": z, "z_L": None}
             else:
-                batch = self.sample(silo, total, seed=seed)
+                batch = self.sample(silo, rows, seed=seed)
             off = 0
             for i in idxs:
                 n = queries[i].n

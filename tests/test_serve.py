@@ -10,9 +10,12 @@ import os
 import subprocess
 import sys
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.federated import serve
 from repro.federated.api import ExperimentSpec, ModelSpec, build
 from repro.federated.population import PopulationSpec
 from repro.federated.scheduler import Scenario
@@ -145,6 +148,140 @@ class TestPosterior:
         assert np.asarray(out).shape == (5, 10)
         np.testing.assert_array_equal(
             np.asarray(out), np.asarray(post.predict(0, x, n=4, seed=2)))
+
+
+_BNN = ModelSpec("hier_bnn", {"in_dim": 16, "hidden": 4,
+                              "train_per_silo": 16, "test_per_silo": 4})
+
+
+@pytest.fixture(scope="module")
+def bnn_post(tmp_path_factory):
+    """A small hier_bnn posterior (local rows of 104 values, a predict
+    hook) shared by the bucket and key tests."""
+    d = tmp_path_factory.mktemp("bnn")
+    _toy_ckpt(d, model=_BNN, num_silos=3)
+    return Posterior.from_checkpoint(str(d))
+
+
+def _split(total):
+    """Query sizes from {64, 16, 4, 1}, largest first, summing to total."""
+    ns = []
+    for n in (64, 16, 4, 1):
+        ns += [n] * ((total - sum(ns)) // n)
+    return ns
+
+
+def _unbucketed(post, kind, silo, total, seed):
+    """A group's draw as the endpoint made it before row buckets: one
+    jitted ``num_samples = total`` call keyed by the eager ``_key``,
+    handed the silo's ``eta_row``."""
+    prob, eta_G = post.problem, post.server.state["eta_G"]
+    if kind == "global_sample":
+        fn = jax.jit(lambda g, k: prob.sample_posterior(
+            g, None, k, num_samples=total)[0])
+        return {"z_G": fn(eta_G, post._key(seed, -1)), "z_L": None}
+    fn = jax.jit(lambda g, l, k: prob.sample_posterior(
+        g, l, k, num_samples=total))
+    z_G, z_L = fn(eta_G, post.eta_row(silo), post._key(seed, silo))
+    return {"z_G": z_G, "z_L": z_L}
+
+
+class TestRowBuckets:
+    @pytest.mark.parametrize("kind", ["sample", "global_sample"])
+    @pytest.mark.parametrize("total", [1, 3, 5, 64, 85, 129, 192, 257])
+    def test_group_answers_are_slices_of_the_unbucketed_draw(
+            self, bnn_post, total, kind):
+        silo = None if kind == "global_sample" else 1
+        ns = _split(total)
+        # A second group in the call must not disturb the first.
+        qs = [Query(kind, silo=silo, n=n) for n in ns]
+        qs.insert(1, Query("sample", silo=2, n=3))
+        ans = bnn_post.answer_batch(qs, seed=11)
+        del ans[1]
+        want = _unbucketed(bnn_post, kind, silo, total, 11)
+        off = 0
+        for n, a in zip(ns, ans):
+            for k, v in want.items():
+                if v is None:
+                    assert a[k] is None
+                else:
+                    np.testing.assert_array_equal(
+                        np.asarray(a[k]), np.asarray(v)[off:off + n])
+            off += n
+
+    @pytest.mark.parametrize("kind", ["sample", "global_sample"])
+    def test_group_totals_compile_one_sampler_per_power_of_two(
+            self, bnn_post, kind):
+        post = Posterior(bnn_post.experiment)
+        silo = None if kind == "global_sample" else 0
+        for total in range(1, 301):
+            ans = post.answer_batch(
+                [Query(kind, silo=silo, n=n) for n in _split(total)])
+            assert sum(np.asarray(a["z_G"]).shape[0] for a in ans) == total
+        rows = sorted(k[1] for k in post._compiled if k[0] == kind)
+        assert rows == [2 ** i for i in range(10)]
+
+    def test_public_sample_is_the_prefix_of_its_bucket(self, bnn_post):
+        got = bnn_post.sample(2, n=5, seed=4)
+        want = _unbucketed(bnn_post, "sample", 2, 5, 4)
+        for k in ("z_G", "z_L"):
+            assert np.asarray(got[k]).shape[0] == 5
+            np.testing.assert_array_equal(np.asarray(got[k]),
+                                          np.asarray(want[k]))
+
+
+class TestServingKey:
+    @pytest.mark.parametrize("silo", [-1, 0, "last"])
+    @pytest.mark.parametrize("seed", [0, 1, 2 ** 31 - 0x53E7 - 1, 2 ** 31,
+                                      -1])
+    def test_in_program_key_equals_the_eager_key(self, bnn_post, seed,
+                                                 silo):
+        post = bnn_post
+        silo = post.num_silos - 1 if silo == "last" else silo
+        key = jax.jit(serve._stream_key)(serve._stream(seed, silo))
+        np.testing.assert_array_equal(np.asarray(key),
+                                      np.asarray(post._key(seed, silo)))
+        # ... and the served draws are made with it.
+        if silo == -1:
+            got = {"z_G": post.global_sample(4, seed=seed)}
+        else:
+            got = post.sample(silo, n=4, seed=seed)
+        want = _unbucketed(post, "global_sample" if silo == -1 else
+                           "sample", silo, 4, seed)
+        for k, v in got.items():
+            np.testing.assert_array_equal(np.asarray(v),
+                                          np.asarray(want[k]))
+
+    @pytest.mark.parametrize("seed", [2 ** 63 - 0x53E7,
+                                      -2 ** 63 - 0x53E7 - 1])
+    def test_seeds_the_eager_key_rejects_stay_rejected(self, bnn_post,
+                                                       seed):
+        with pytest.raises(OverflowError):
+            bnn_post._key(seed, 0)
+        with pytest.raises(OverflowError):
+            bnn_post.answer_batch([Query("sample", silo=0)], seed=seed)
+
+
+@pytest.mark.parametrize("n", [1, 4, 16, 64])
+def test_predict_answers_unchanged(bnn_post, n):
+    """predict against its program as it was: the row and the eager key
+    handed in, a mean over exactly n draws."""
+    post = bnn_post
+    prob, st = post.problem, post.server.state
+    predict = prob.model.predict
+
+    def run(theta, eta_G, eta_L, x, k):
+        z_G, z_L = prob.sample_posterior(eta_G, eta_L, k, num_samples=n)
+        out = jax.vmap(lambda zg, zl: predict(theta, zg, zl, x))(z_G, z_L)
+        return jnp.mean(out, axis=0)
+
+    x = np.random.default_rng(n).normal(size=(5, 16)).astype(np.float32)
+    want = jax.jit(run)(st["theta"], st["eta_G"], post.eta_row(2),
+                        jnp.asarray(x), post._key(3, 2))
+    np.testing.assert_array_equal(np.asarray(post.predict(2, x, n=n, seed=3)),
+                                  np.asarray(want))
+    ans = post.answer_batch([Query("predict", silo=2, n=n, x=x)], seed=3)
+    np.testing.assert_array_equal(np.asarray(ans[0]), np.asarray(want))
 
 
 class TestCLI:
