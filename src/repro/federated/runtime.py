@@ -540,6 +540,21 @@ class Server:
             return mask
         return jnp.concatenate([mask, jnp.zeros((pad,), mask.dtype)])
 
+    def _control(self, host: PyTree) -> PyTree:
+        """Host control inputs → the round's replicated device arrays.
+
+        One transfer for the whole tree. A multi-process run needs
+        global arrays; every process computed the identical host values
+        (scheduler and key stream are pure functions of seed and
+        absolute round).
+        """
+        if self.n_processes > 1:
+            from repro.federated import distributed
+
+            return jax.tree_util.tree_map(
+                lambda x: distributed.replicated(x, self.mesh), host)
+        return jax.device_put(host, NamedSharding(self.mesh, P()))
+
     # -- dynamic population growth ------------------------------------------
 
     def grow_silos(self, datas: Sequence[PyTree],
@@ -1184,6 +1199,7 @@ class Server:
         with debug.host_bridge():
             # repro-lint: allow[R1] — root of the round stream; every key below folds in the absolute round index, so resume replays it exactly
             base_key = jax.random.PRNGKey(self.seed)
+            n_j, n_j_for = self._control(self.num_obs), self.J
         for r in range(start_round, start_round + num_rounds):
             # A step-cadence strategy synchronizes every local step, so
             # each of the round's `exchanges` gathers is its OWN
@@ -1193,10 +1209,9 @@ class Server:
             # one draw per round.
             ex_idx = ([r * local_steps + t for t in range(local_steps)]
                       if step_cadence else [r])
-            # Mask/key construction transfers tiny host scalars to
-            # device, so it runs in the sanctioned control-plane window
-            # (repro.debug.host_bridge); metric pulls below stay under
-            # the transfer guard and must use explicit device_get.
+            # The control plane transfers tiny host values to device, so
+            # it runs in the sanctioned window (repro.debug.host_bridge);
+            # the ELBO pull below stays under the transfer guard.
             with debug.host_bridge():
                 present = stale_w = None
                 if population is not None:
@@ -1205,50 +1220,33 @@ class Server:
                     # and staleness vectors cover the post-growth J.
                     present, stale_w = population.begin_round(self, r)
                     fn = self._get_round(strat, local_steps)
-                raw_masks = [sched.mask(i) for i in ex_idx]
+                    if self.J != n_j_for:
+                        n_j, n_j_for = self._control(self.num_obs), self.J
+                # The round's E exchange masks in one draw and one pull:
+                # (E, J) host copies that the meter counts and that ride
+                # the round in one transfer.
+                inv, rep = jax.device_get(sched.round_masks(ex_idx))
                 if present is not None:
-                    pr = jnp.asarray(present)
-                    sw = jnp.asarray(stale_w)
-                    ex_masks = [m[: self.J] * pr for m in raw_masks]
-                    wt_masks = [m[: self.J] * sw for m in raw_masks]
+                    # The scheduler is roster-wide; slice to the joined J.
+                    wts = rep[:, : self.J] * stale_w
+                    rep = rep[:, : self.J] * present
+                    inv = inv[:, : self.J] * present
                 else:
-                    ex_masks = raw_masks
-                    wt_masks = raw_masks
-                padded = [self._pad_mask(m) for m in ex_masks]
-                padded_w = [self._pad_mask(w) for w in wt_masks]
-                mask = (jnp.stack(padded) if step_cadence else padded[0])
-                weights = (jnp.stack(padded_w) if step_cadence
-                           else padded_w[0])
-                n_j = jnp.asarray(self.num_obs)
+                    wts = rep
+                mask = np.zeros((len(ex_idx), self.J_pad), np.float32)
+                weights = np.zeros_like(mask)
+                mask[:, : self.J], weights[:, : self.J] = rep, wts
+                if not step_cadence:
+                    mask, weights = mask[0], weights[0]
+                mask, weights = self._control((mask, weights))
                 round_key = jax.random.fold_in(base_key, r)
                 if self.n_processes > 1:
-                    # Control inputs must be global arrays in a
-                    # multi-process run; every process computed the
-                    # identical host values (scheduler and key stream
-                    # are pure functions of seed and absolute round).
-                    from repro.federated import distributed
-
-                    mask = distributed.replicated(mask, self.mesh)
-                    weights = distributed.replicated(weights, self.mesh)
-                    n_j = distributed.replicated(n_j, self.mesh)
-                    round_key = distributed.replicated(
-                        round_key, self.mesh)
-                # Stragglers received the broadcast before dropping:
-                # bill their download. Schedulers without the optional
-                # invited() protocol attribute bill reporters — and an
-                # absent silo receives no broadcast at all.
-                invited_fn = getattr(sched, "invited", None)
-                inv_masks = [
-                    invited_fn(i) if invited_fn is not None else ex_masks[k]
-                    for k, i in enumerate(ex_idx)
-                ]
-                if present is not None:
-                    inv_masks = [m[: self.J] * pr for m in inv_masks]
-            active = [int(np.sum(jax.device_get(m))) for m in ex_masks]
-            invited = [
-                max(int(np.sum(jax.device_get(m))), active[k])
-                for k, m in enumerate(inv_masks)
-            ]
+                    round_key = self._control(round_key)
+            # Stragglers received the broadcast before dropping: bill
+            # their download; an absent silo receives no broadcast.
+            active = [int(a) for a in rep.sum(axis=1)]
+            invited = [max(int(i), a)
+                       for i, a in zip(inv.sum(axis=1), active, strict=True)]
             # Sync rounds aggregate with the participation mask itself
             # (population churn decays a returning silo's weight); the
             # async engine passes staleness-decayed weights instead.

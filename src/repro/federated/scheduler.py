@@ -17,6 +17,7 @@ scenario space (``python -m repro.federated.run --sweep``).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 from typing import Optional, Sequence
 
@@ -66,10 +67,21 @@ class RoundScheduler:
     dropout: float = 0.0
     seed: int = 0
 
-    def _keys(self, round_idx: int):
-        # repro-lint: allow[R1] — participation stream root, folded with the absolute round index on the same line
-        key = jax.random.fold_in(jax.random.PRNGKey(self.seed), round_idx)
-        return jax.random.split(key)
+    def round_masks(self, indices: Sequence[int]):
+        """(invited, reported): two (E, J) float32 masks for E indices.
+
+        One jitted, vmapped draw per call (:func:`_draw`), so a round
+        of K synchronised exchanges costs one dispatch and one pull,
+        not K. Row ``e`` is the draw of schedule index ``indices[e]``:
+        ``invited`` is who the server broadcasts to, ``reported`` who
+        uploads (stragglers are invited but absent).
+        """
+        # PRNGKey reads its seed modulo 2**32; the uint32 keeps every
+        # seed in range of the traced argument.
+        return _draw(np.uint32(self.seed % 2 ** 32),
+                     np.asarray(indices, np.int32), J=self.num_silos,
+                     participation=float(self.participation),
+                     dropout=float(self.dropout))
 
     def invited(self, round_idx: int) -> jnp.ndarray:
         """(J,) float32 mask of silos the server *broadcasts to* this round.
@@ -77,46 +89,48 @@ class RoundScheduler:
         Stragglers (``dropout``) are invited — they receive (θ, η_G) and
         cost download bytes — but may still be absent from :meth:`mask`.
         """
-        k_inv, _ = self._keys(round_idx)
-        J = self.num_silos
-        mask = np.ones((J,), np.float32)
-        if self.participation < 1.0:
-            # Half-up, not Python's round(): banker's rounding resolves
-            # the .5 tie to the nearest EVEN count, so participation=0.5
-            # with J=5 invited round(2.5) = 2 silos instead of the
-            # documented "fraction of silos" (3). Even-J schedules are
-            # unchanged (their products never tie on .5 at x.0 inputs).
-            n_inv = max(1, int(self.participation * J + 0.5))
-            chosen = np.asarray(
-                jax.random.choice(k_inv, J, shape=(n_inv,), replace=False)
-            )
-            mask = np.zeros((J,), np.float32)
-            mask[chosen] = 1.0
-        return jnp.asarray(mask)
+        return self.round_masks([round_idx])[0][0]
 
     def mask(self, round_idx: int) -> jnp.ndarray:
         """(J,) float32 mask: 1.0 = silo reports this round, 0.0 = absent."""
-        _, k_drop = self._keys(round_idx)
-        J = self.num_silos
-        mask = np.asarray(self.invited(round_idx)).copy()
-        if self.dropout > 0.0:
-            survive = np.asarray(
-                jax.random.bernoulli(k_drop, 1.0 - self.dropout, (J,))
-            ).astype(np.float32)
-            dropped = mask * survive
-            # Never lose the whole round: keep the lowest-index invited silo.
-            mask = dropped if dropped.any() else _first_invited(mask)
-        return jnp.asarray(mask)
+        return self.round_masks([round_idx])[1][0]
 
     def masks(self, num_rounds: int) -> jnp.ndarray:
         """(num_rounds, J) stacked schedule (for logging / tests)."""
-        return jnp.stack([self.mask(r) for r in range(num_rounds)])
+        return self.round_masks(range(num_rounds))[1]
 
 
-def _first_invited(mask: np.ndarray) -> np.ndarray:
-    out = np.zeros_like(mask)
-    out[int(np.argmax(mask))] = 1.0
-    return out
+@functools.partial(jax.jit,
+                   static_argnames=("J", "participation", "dropout"))
+def _draw(seed, indices, *, J: int, participation: float, dropout: float):
+    """The schedule rule, vmapped over schedule indices (see round_masks)."""
+
+    def one(i):
+        # repro-lint: allow[R1] — participation stream root, folded with the absolute schedule index on the same line
+        key = jax.random.fold_in(jax.random.PRNGKey(seed), i)
+        k_inv, k_drop = jax.random.split(key)
+        invited = jnp.ones((J,), jnp.float32)
+        if participation < 1.0:
+            # Half-up, not Python's round(): banker's rounding resolves
+            # the .5 tie to the nearest EVEN count, so participation=0.5
+            # with J=5 would invite round(2.5) = 2 silos instead of the
+            # documented "fraction of silos" (3).
+            n_inv = max(1, int(participation * J + 0.5))
+            chosen = jax.random.choice(k_inv, J, shape=(n_inv,),
+                                       replace=False)
+            invited = jnp.zeros((J,), jnp.float32).at[chosen].set(1.0)
+        reported = invited
+        if dropout > 0.0:
+            survive = jax.random.bernoulli(
+                k_drop, 1.0 - dropout, (J,)).astype(jnp.float32)
+            dropped = invited * survive
+            # Never lose the whole round: keep the lowest-index invitee.
+            first = jax.nn.one_hot(jnp.argmax(invited), J,
+                                   dtype=jnp.float32)
+            reported = jnp.where(jnp.any(dropped), dropped, first)
+        return invited, reported
+
+    return jax.vmap(one)(indices)
 
 
 # ---------------------------------------------------------------------------

@@ -205,10 +205,82 @@ class TestCompression:
         assert srv.bytes_up_per_silo("sfvi") < raw
 
 
+class EagerRoundScheduler(RoundScheduler):
+    """Oracle: the per-index eager draw the batched ``round_masks``
+    replaced, copied verbatim (``_keys``/``invited``/``mask``), so the
+    batched draw is held to it bit for bit."""
+
+    def _keys(self, round_idx: int):
+        # repro-lint: allow[R1] — participation stream root, folded with the absolute round index on the same line
+        key = jax.random.fold_in(jax.random.PRNGKey(self.seed), round_idx)
+        return jax.random.split(key)
+
+    def invited(self, round_idx: int) -> jnp.ndarray:
+        k_inv, _ = self._keys(round_idx)
+        J = self.num_silos
+        mask = np.ones((J,), np.float32)
+        if self.participation < 1.0:
+            n_inv = max(1, int(self.participation * J + 0.5))
+            chosen = np.asarray(
+                jax.random.choice(k_inv, J, shape=(n_inv,), replace=False)
+            )
+            mask = np.zeros((J,), np.float32)
+            mask[chosen] = 1.0
+        return jnp.asarray(mask)
+
+    def mask(self, round_idx: int) -> jnp.ndarray:
+        _, k_drop = self._keys(round_idx)
+        J = self.num_silos
+        mask = np.asarray(self.invited(round_idx)).copy()
+        if self.dropout > 0.0:
+            survive = np.asarray(
+                jax.random.bernoulli(k_drop, 1.0 - self.dropout, (J,))
+            ).astype(np.float32)
+            dropped = mask * survive
+            mask = dropped if dropped.any() else _first_invited(mask)
+        return jnp.asarray(mask)
+
+    def round_masks(self, indices):
+        """The oracle's rows, stacked as host (E, J) arrays."""
+        return (np.stack([np.asarray(self.invited(i)) for i in indices]),
+                np.stack([np.asarray(self.mask(i)) for i in indices]))
+
+
+def _first_invited(mask: np.ndarray) -> np.ndarray:
+    out = np.zeros_like(mask)
+    out[int(np.argmax(mask))] = 1.0
+    return out
+
+
 class TestScheduling:
     def test_masks_are_deterministic(self):
         s = RoundScheduler(8, participation=0.5, dropout=0.2, seed=3)
         np.testing.assert_array_equal(s.mask(5), s.mask(5))
+
+    @pytest.mark.parametrize("J,participation,dropout,seed", [
+        (3, 1.0, 0.0, 7), (8, 0.5, 0.2, 3), (64, 0.25, 0.5, 11),
+        (5, 0.5, 0.99, 0), (4, 0.01, 0.0, 0), (4, 1.0, 1.0, 5),
+    ])
+    @pytest.mark.parametrize("start", [0, 1000])
+    def test_batched_draw_matches_eager_per_index_draw(
+            self, J, participation, dropout, seed, start):
+        """round_masks over 25 indices is the per-index eager draw,
+        bit for bit; mask/invited/masks are views of its rows."""
+        knobs = dict(participation=participation, dropout=dropout,
+                     seed=seed)
+        s = RoundScheduler(J, **knobs)
+        idx = range(start, start + 25)
+        inv, rep = jax.device_get(s.round_masks(idx))
+        assert inv.dtype == rep.dtype == np.float32
+        assert inv.shape == rep.shape == (25, J)
+        ref_inv, ref_rep = EagerRoundScheduler(J, **knobs).round_masks(idx)
+        np.testing.assert_array_equal(inv, ref_inv)
+        np.testing.assert_array_equal(rep, ref_rep)
+        for e, i in enumerate(idx):
+            np.testing.assert_array_equal(s.mask(i), rep[e])
+            np.testing.assert_array_equal(s.invited(i), inv[e])
+        if start == 0:
+            np.testing.assert_array_equal(s.masks(25), rep)
 
     def test_participation_counts(self):
         s = RoundScheduler(8, participation=0.5, seed=0)

@@ -149,3 +149,87 @@ class TestTreeBytes:
 
     def test_empty(self):
         assert tree_bytes({}) == 0
+
+
+class TestControlPlane:
+    """Server.run's host control plane: one mask draw and one pull per
+    round, metered from the host copy."""
+
+    @pytest.mark.parametrize("K,churn", [(1, False), (25, False), (3, True)])
+    def test_two_pulls_per_round_and_history_matches_eager_masks(
+            self, monkeypatch, K, churn):
+        from test_federated import EagerRoundScheduler
+
+        from repro.federated import runtime
+        from repro.federated.api import ExperimentSpec, ModelSpec, build
+        from repro.federated.population import PopulationSpec
+        from repro.federated.scheduler import Scenario
+
+        J, R = 5, (12 if churn else 4)
+        knobs = dict(participation=0.6, dropout=0.3, seed=2)
+        spec = ExperimentSpec(
+            model=ModelSpec("toy"),
+            scenario=Scenario(algorithm="sfvi", participation=0.6,
+                              dropout=0.3),
+            num_silos=J, rounds=R, local_steps=K, seed=2,
+            population=(PopulationSpec(initial=2, arrival_rate=0.6,
+                                       departure_rate=0.2, return_rate=0.5,
+                                       seed=3) if churn else None))
+        exp = build(spec)
+        assert exp.scheduler == runtime.RoundScheduler(J, **knobs)
+        eager = EagerRoundScheduler(J, **knobs)
+        ref = build(spec)
+        ref.scheduler = eager
+        h_ref = ref.run()
+
+        pulls, sent, begun = [], [], []
+        device_get, control = runtime.jax.device_get, exp.server._control
+
+        def count_pull(x):
+            pulls.append(x)
+            return device_get(x)
+
+        def keep_sent(host):
+            if isinstance(host, tuple):  # the round's (mask, weights)
+                sent.append(host)
+            return control(host)
+
+        monkeypatch.setattr(runtime.jax, "device_get", count_pull)
+        monkeypatch.setattr(exp.server, "_control", keep_sent)
+        if churn:
+            begin_round = exp.population.begin_round
+
+            def keep_begun(server, r):
+                out = begin_round(server, r)
+                begun.append(out)
+                return out
+
+            monkeypatch.setattr(exp.population, "begin_round", keep_begun)
+        exp.run(rounds=1)  # compiles; counted like any other round
+        h = exp.run()
+        assert len(pulls) == 2 * R
+        assert h["elbo_trace"] == h_ref["elbo_trace"]
+
+        # The meter and the round's inputs, rebuilt from the eager draw.
+        up1 = exp.server.bytes_up_per_silo()
+        down1 = exp.server.bytes_down_per_silo()
+        for r in range(R):
+            inv, rep = eager.round_masks(range(r * K, (r + 1) * K))
+            present, stale_w = begun[r] if churn else (np.ones(J),) * 2
+            j = len(present)
+            inv, wts, rep = (inv[:, :j] * present, rep[:, :j] * stale_w,
+                             rep[:, :j] * present)
+            mask, weights = sent[r]
+            np.testing.assert_array_equal(mask[:, :j], rep)
+            np.testing.assert_array_equal(weights[:, :j], wts)
+            assert not mask[:, j:].any() and not weights[:, j:].any()
+            active = rep.sum(axis=1).astype(int)
+            invited = np.maximum(inv.sum(axis=1).astype(int), active)
+            assert h["bytes_up"][r] == active.sum() * up1
+            assert h["bytes_down"][r] == invited.sum() * down1
+            assert h["n_active"][r] == active[-1]
+        assert len(set(h["n_active"])) > 1  # the draws really vary
+        if churn:  # silos joined, departed and came back stale
+            assert exp.population.state.joined > 2
+            assert any((p == 0).any() for p, _ in begun)
+            assert any(((w > 0) & (w < 1)).any() for _, w in begun)
