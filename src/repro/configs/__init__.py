@@ -21,6 +21,7 @@ from repro.configs.olmoe_1b_7b import CONFIG as _olmoe
 from repro.configs.qwen2_vl_2b import CONFIG as _qwen2vl
 from repro.configs.xlstm_1_3b import CONFIG as _xlstm
 from repro.configs.phi3_5_moe import CONFIG as _phi35
+from repro.configs.nemotron3_nano import CONFIG as _nemotron3
 
 REGISTRY: Dict[str, ArchConfig] = {
     c.name: c
@@ -35,6 +36,7 @@ REGISTRY: Dict[str, ArchConfig] = {
         _qwen2vl,
         _xlstm,
         _phi35,
+        _nemotron3,
     ]
 }
 

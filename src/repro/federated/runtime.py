@@ -814,8 +814,9 @@ class Server:
         # — per-silo counts ride the jit boundary as the n_j argument.
         key = (strat.cache_key(), local_steps, self.J_pad)
         if key not in self._round_fns:
+            accumulate = self.accumulates(strat)
             if strat.cadence == "step":
-                body = self._step_body(strat, local_steps)
+                body = self._step_body(strat, local_steps, accumulate)
             elif strat.cadence == "round":
                 body = self._round_body(strat, local_steps)
             else:
@@ -878,10 +879,13 @@ class Server:
             # r's compiled graph. Control inputs are small and replicate.
             state_sh, data_sh = self._shardings()
             rep = NamedSharding(self.mesh, P())
+            # A round that accumulates holds no spare copy of the state
+            # either: the new state is written over the old one.
             self._round_fns[key] = jax.jit(
                 round_fn,
                 in_shardings=(state_sh, data_sh, rep, rep, rep, rep),
                 out_shardings=(state_sh, {"elbo": rep}),
+                donate_argnums=(0,) if accumulate else (),
             )
         return self._round_fns[key]
 
@@ -941,8 +945,38 @@ class Server:
             ref = wire.pack(ref)
         return ref
 
-    def _step_body(self, strat: ServerStrategy, K: int) -> Callable:
-        """Round = K synchronized steps: gather + server update every step."""
+    def accumulates(self, algorithm=None) -> bool:
+        """Whether the round sums the silos' uploads one silo after another.
+
+        The stacked round holds every local silo's upload twice — its
+        gradient tree and its packed wire row — before one gather and one
+        combine. When that stack would take more than a quarter of a
+        device's memory (a backbone θ of hundreds of millions of floats),
+        a step-cadence round with the plain mean combine — no DP, no
+        compression, no robust aggregator, a 1-D silo mesh — instead runs
+        each device's silos in turn into one running sum of the upload's
+        P floats, then sums those over ``silo`` (the gather's place), and
+        donates the round's state (arrays handed to the Server are
+        consumed by its first round). The meter still bills every silo's
+        row. Everything is read from the configuration and the device;
+        no option selects it.
+        """
+        strat = self._resolve(algorithm)
+        if (strat.cadence != "step" or self.wire == "legacy"
+                or self.privacy is not None or self.model_world > 1
+                or _wire_codec(self.compressor) != "identity"
+                or getattr(self.aggregator, "fused_reduction", None) != "mean"):
+            return False
+        per_device = self.J_pad // int(self.mesh.shape["silo"])
+        stacked = 2 * per_device * self.wire_spec(strat).dim * 4
+        stats = self.mesh.local_devices[0].memory_stats() or {}
+        return stacked > stats.get("bytes_limit", float("inf")) / 4
+
+    def _step_body(self, strat: ServerStrategy, K: int,
+                   accumulate: bool = False) -> Callable:
+        """Round = K synchronized steps: gather + server update every step
+        (or, with ``accumulate``, a running sum over silos; see
+        :meth:`accumulates`)."""
         problem = self.problem
         agg, comp = self.aggregator, self.compressor
         privacy = self.privacy
@@ -956,6 +990,40 @@ class Server:
         int8 = _wire_codec(comp) == "int8"
         trim = self._fused_trim()
         ctx = self._ctx(K, wire)
+        # Shapes only: a device array captured here would stay alive as
+        # long as the cached round program.
+        template = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, jnp.float32),
+            strat.ship_template(self)) if accumulate else None
+
+        def silo_sum(theta, eta_G, eta_L, opt_L, strat_state, data_sh,
+                     sids, mask_sh, w_sh, n_j, round_key, t, eps_G, w_full):
+            """This device's silos one after another into a running sum
+            of their weighted uploads; the sum over ``silo`` over the
+            total weight is the plain mean of the stacked combine."""
+            def one(acc, xs):
+                eta_Lj, opt_Lj, st_j, data_j, sid, m_j, w_j, n_obs_j = xs
+                eta_Lj, opt_Lj, st_j, ship, hatLj = strat.silo_step(
+                    ctx, theta, eta_G, eta_Lj, opt_Lj, st_j,
+                    data_j, sid, m_j, n_obs_j, round_key, t, eps_G,
+                )
+                acc = jax.tree_util.tree_map(
+                    lambda a, g: a + jnp.where(m_j > 0.5, w_j * g, 0.0),
+                    acc, ship)
+                return acc, (eta_Lj, opt_Lj, st_j, hatLj * m_j)
+
+            with jax.named_scope("round.silo_sum"):
+                acc = jax.tree_util.tree_map(
+                    lambda x: jnp.zeros(x.shape, jnp.float32), template)
+                acc, (eta_L, opt_L, strat_state, hatL) = jax.lax.scan(
+                    one, acc, (eta_L, opt_L, strat_state, data_sh, sids,
+                               mask_sh, w_sh, n_j))
+                acc = jax.lax.psum(acc, "silo")
+            total = jnp.sum(w_full)
+            denom = jnp.where(total > 0.0, total, 1.0)
+            mean_g = jax.tree_util.tree_map(lambda a: a / denom, acc)
+            hatL_sum = jax.lax.psum(jnp.sum(hatL), "silo")
+            return eta_L, opt_L, strat_state, mean_g, hatL_sum
 
         def body(theta, eta_G, opt_server, eta_L, opt_L, strat_state,
                  data_sh, sids, n_j, masks_full, weights_full, round_key):
@@ -974,6 +1042,17 @@ class Server:
                 (theta, eta_G, opt_server, eta_L, opt_L,
                  strat_state) = carry
                 eps_G = global_eps(problem, round_key, t)
+                if accumulate:
+                    eta_L, opt_L, strat_state, mean_g, hatL_sum = silo_sum(
+                        theta, eta_G, eta_L, opt_L, strat_state, data_sh,
+                        sids, mask_sh, w_full[sids], n_j, round_key, t,
+                        eps_G, w_full)
+                    theta, eta_G, opt_server, elbo = strat.server_step(
+                        ctx, theta, eta_G, opt_server, mean_g, hatL_sum,
+                        n_active, eps_G,
+                    )
+                    return (theta, eta_G, opt_server, eta_L, opt_L,
+                            strat_state), elbo
                 ref = self._packed_reference(strat, ctx, wire, theta, eta_G)
 
                 def per_silo(eta_Lj, opt_Lj, st_j, data_j, sid, m_j,

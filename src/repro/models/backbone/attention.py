@@ -50,7 +50,7 @@ def _project_qkv(params, cfg, x, positions):
     if cfg.qk_norm:
         q = rmsnorm(q, params["q_norm"], cfg.norm_eps)
         k = rmsnorm(k, params["k_norm"], cfg.norm_eps)
-    if positions is not None:
+    if positions is not None and cfg.attn_rope:
         if cfg.mrope:
             q = apply_mrope(q, positions, cfg.rope_theta)
             k = apply_mrope(k, positions, cfg.rope_theta)

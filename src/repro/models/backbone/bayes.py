@@ -1,5 +1,5 @@
 """SFVI <-> backbone integration: the paper's structured latent decomposition
-applied to LLM-scale architectures (DESIGN.md §3, "fully-Bayesian FedPop"
+applied to LLM-scale architectures (the "fully-Bayesian FedPop"
 generalization of paper §4.1).
 
     θ    = backbone weights (embedding, blocks, head)            — trainable
@@ -25,6 +25,12 @@ across silos exactly as SFVI prescribes.
 The variational family is the paper's diagonal Gaussian over both Z_G and
 (batched over silos) Z_Lj — the same choice the paper makes for its
 high-dimensional MNIST experiment (§S2.1).
+
+``sfvi_problem`` packages a backbone and this head as the repository's
+:class:`~repro.core.sfvi.SFVIProblem`, so a backbone trains through the
+normal path (spec -> ``build`` -> ``Server`` -> ``Experiment.run``) like
+the paper's models: θ is the backbone pytree, and a silo's data is
+``{"tokens": (n_seq, S + 1) int32}`` scored next-token.
 """
 from __future__ import annotations
 
@@ -127,3 +133,33 @@ def silo_log_lik(cfg, base_logits_j, h_j, z_G, z_Lj, labels_j):
     """log p(y_j | Z_G, Z_Lj, θ) for one silo's batch shard."""
     logits = bayes_logits(cfg, base_logits_j, h_j, z_G, z_Lj)
     return -token_nll(logits, labels_j, masked_gather=cfg.perf.masked_nll)
+
+
+def log_local(cfg: ArchConfig, theta: PyTree, z_G: jnp.ndarray,
+              z_L: jnp.ndarray, data_j: Dict[str, jnp.ndarray]) -> jnp.ndarray:
+    """log p(Z_Lj | Z_G) + Σ next-token log-likelihood of silo j's
+    sequences under the backbone θ and the Bayesian head."""
+    from repro.models.backbone import transformer
+
+    tokens = data_j["tokens"]
+    base, _, h = transformer.forward(theta, cfg, {"tokens": tokens[:, :-1]},
+                                     remat=True)
+    with jax.named_scope("bayes_head"):
+        ll = silo_log_lik(cfg, base, h, z_G, z_L, tokens[:, 1:])
+    return log_prior_local(cfg, z_G, z_L) + ll
+
+
+def sfvi_problem(cfg: ArchConfig):
+    """The backbone under the Bayesian head as an SFVI problem: diagonal
+    Gaussian q over Z_G and over each silo's Z_Lj (no auxiliary loss —
+    the objective is the ELBO alone)."""
+    from repro.core import DiagGaussian, SFVIProblem, StructuredModel
+
+    n_G, n_L = latent_dims(cfg)
+    model = StructuredModel(
+        global_dim=n_G, local_dim=n_L,
+        log_prior_global=lambda theta, z_G: log_prior_global(cfg, z_G),
+        log_local=lambda theta, z_G, z_L, d: log_local(cfg, theta, z_G, z_L, d),
+        name=f"bayes_lm[{cfg.name}]",
+    )
+    return SFVIProblem(model, DiagGaussian(n_G), DiagGaussian(n_L))

@@ -10,12 +10,17 @@ import dataclasses
 from typing import Literal, Optional, Tuple
 
 ArchType = Literal["dense", "moe", "ssm", "hybrid", "audio", "vlm"]
-BlockKind = Literal["attn", "mamba2", "mlstm", "slstm"]
+BlockKind = Literal["attn", "mamba2", "mlstm", "slstm", "moe"]
+
+# One character per block of a ``layer_pattern`` (Nemotron-H's
+# ``hybrid_override_pattern``): each block holds ONE sublayer behind its
+# own pre-norm and residual.
+PATTERN_KINDS = {"M": "mamba2", "E": "moe", "*": "attn"}
 
 
 @dataclasses.dataclass(frozen=True)
 class BayesConfig:
-    """SFVI latent decomposition for LLM-scale models (DESIGN.md §3).
+    """SFVI latent decomposition for LLM-scale models (``bayes.py``).
 
     θ   = backbone weights;
     Z_G = global Gaussian latent over a rank-r LM-head adapter;
@@ -80,12 +85,28 @@ class ArchConfig:
     num_experts_per_tok: int = 0
     d_expert: int = 0  # per-expert FFN width (olmoe: 1024)
     capacity_factor: float = 1.25
+    # Dropless expert layer (``moe.moe_dropless``, the "E" blocks of a
+    # ``layer_pattern``; relu^2 experts): the router scores
+    # ``router_experts`` experts (0 = num_experts) and this device holds
+    # the first ``num_experts`` of them; sigmoid scores with a
+    # selection-only bias, top-k renormalised, times ``routed_scaling``.
+    router_experts: int = 0
+    routed_scaling: float = 1.0
+    d_shared_expert: int = 0  # one always-on shared expert (0 = none)
 
     # SSM (mamba2)
     ssm_state: int = 0
     ssm_conv: int = 4
     ssm_expand: int = 2
     ssm_head_dim: int = 64
+    ssm_heads: int = 0  # 0 = ssm_expand * d_model / ssm_head_dim
+    ssm_groups: int = 1  # B/C groups; heads split evenly over them
+    ssm_chunk: int = 256  # SSD chunk length
+
+    # Per-block pattern (one sublayer per block, see PATTERN_KINDS); the
+    # first ``num_layers`` characters are the blocks built.
+    layer_pattern: str = ""
+    attn_rope: bool = True
 
     # hybrid (zamba2): attention block period; others are mamba2
     hybrid_attn_period: int = 0  # 0 = not hybrid; e.g. 6 = every 6th block is attn
@@ -135,6 +156,17 @@ class ArchConfig:
         return self.num_experts > 0
 
     @property
+    def ssm_inner(self) -> int:
+        """Mamba-2 inner width: heads x head_dim."""
+        if self.ssm_heads:
+            return self.ssm_heads * self.ssm_head_dim
+        return self.ssm_expand * self.d_model
+
+    @property
+    def num_router_experts(self) -> int:
+        return self.router_experts or self.num_experts
+
+    @property
     def padded_vocab(self) -> int:
         """Embed/head table rows. §Perf lever 2: padding the vocab to a
         multiple of 256 makes the head matmul and the (B,S,V) logits
@@ -145,6 +177,8 @@ class ArchConfig:
 
     def block_kind(self, layer_idx: int) -> BlockKind:
         """Which block family does layer ``layer_idx`` use?"""
+        if self.layer_pattern:
+            return PATTERN_KINDS[self.layer_pattern[layer_idx]]
         if self.arch_type == "hybrid" and self.hybrid_attn_period:
             return "attn" if (layer_idx % self.hybrid_attn_period) == (self.hybrid_attn_period - 1) else "mamba2"
         if self.arch_type == "ssm" and self.slstm_period:
@@ -162,7 +196,7 @@ class ArchConfig:
     def supports_long_context(self) -> bool:
         """long_500k eligibility: recurrent state or sliding window."""
         if self.is_encoder_decoder:
-            return False  # see DESIGN.md §Arch-applicability (whisper skip)
+            return False  # cross-attention over a fixed 30 s encoder window
         if self.arch_type in ("ssm", "hybrid"):
             return True
         return self.sliding_window is not None
@@ -178,7 +212,29 @@ class ArchConfig:
         )
 
     def reduced(self) -> ArchConfig:
-        """CPU smoke-test variant of the same family."""
+        """CPU smoke-test variant of the same family.
+
+        A ``layer_pattern`` model keeps its pattern's first period (up to
+        and including the first attention block), so every block kind is
+        present; every width is cut to a CPU size.
+        """
+        if self.layer_pattern:
+            return dataclasses.replace(
+                self,
+                name=self.name + "-smoke",
+                num_layers=min(self.num_layers,
+                               self.layer_pattern.index("*") + 1),
+                d_model=64, num_heads=4, num_kv_heads=2, head_dim=16,
+                vocab_size=256,
+                num_experts=min(self.num_experts, 4),
+                router_experts=min(self.num_router_experts, 16),
+                num_experts_per_tok=min(self.num_experts_per_tok, 3),
+                d_expert=32, d_shared_expert=48 if self.d_shared_expert else 0,
+                ssm_state=16, ssm_heads=8, ssm_head_dim=8,
+                ssm_groups=min(self.ssm_groups, 2), ssm_chunk=8,
+                dtype="float32",
+                bayes=BayesConfig(global_rank=2, local_rank=1),
+            )
         return dataclasses.replace(
             self,
             name=self.name + "-smoke",

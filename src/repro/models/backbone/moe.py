@@ -1,7 +1,19 @@
-"""Mixture-of-Experts layer: top-k routing with capacity-based einsum
-dispatch (the classic shard-friendly formulation of GShard / Switch / t5x).
+"""Mixture-of-Experts layers.
 
-TPU adaptation (DESIGN.md §5): the expert dimension E is sharded along the
+Two formulations:
+
+* ``moe_block`` — top-k softmax routing with capacity-based einsum
+  dispatch (the classic shard-friendly formulation of GShard / Switch /
+  t5x), used by olmoe and phi3.5-moe;
+* ``moe_dropless`` — the expert layer of an expert-parallel deployment,
+  used by Nemotron-H: it is told which experts it holds, routes every
+  token over ALL experts (DeepSeek-V3 rule: sigmoid scores, a
+  selection-only correction bias, top-k renormalised, times
+  ``routed_scaling``), and computes only its held experts' gated part,
+  grouping tokens by held expert for ``jax.lax.ragged_dot`` and dropping
+  none; plus the always-on shared expert. Experts are relu^2 MLPs.
+
+Capacity path — the expert dimension E is sharded along the
 ``model`` mesh axis (expert parallelism); tokens arrive sharded along
 ``data``. The dispatch einsum reshards (groups@data, E, C, D) ->
 (E@model, ...) — XLA SPMD lowers that resharding to the all-to-all that a
@@ -22,6 +34,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.custom_batching import custom_vmap
 
 from repro.models.backbone.layers import dense_init
 
@@ -129,3 +142,144 @@ def moe_block_dense(params, cfg, x) -> Tuple[jnp.ndarray, jnp.ndarray]:
     y_e = jnp.einsum("etf,efd->etd", jax.nn.silu(h) * u, params["w_down"])
     y = jnp.einsum("te,etd->td", gates.astype(x.dtype), y_e)
     return y.reshape(B, S, D), aux
+
+
+# ---------------------------------------------------------------------------
+# Dropless expert layer (expert parallelism: this device's held experts)
+# ---------------------------------------------------------------------------
+
+def _batch_all(fn):
+    """``fn`` with a vmap rule that maps it over the batch, one element
+    after another.
+
+    ``jax.lax.ragged_dot`` does not batch over operands that lack the batch
+    axis (under the runtime's vmap over silos the expert weights are θ,
+    shared by every silo), and the TPU compiler takes no batched ragged
+    dot at all.
+    """
+    f = custom_vmap(fn)
+
+    @f.def_vmap
+    def rule(axis_size, in_batched, *args):
+        args = [a if b else jnp.broadcast_to(a, (axis_size,) + a.shape)
+                for a, b in zip(args, in_batched, strict=True)]
+        out = jax.lax.map(lambda a: fn(*a), args)
+        return out, jax.tree_util.tree_map(lambda _: True, out)
+
+    return f
+
+
+def _ragged(x, w, sizes):
+    return jax.lax.ragged_dot(x, w, sizes, preferred_element_type=jnp.float32)
+
+
+def _ragged_vjp(x, w, sizes, g):
+    return jax.vjp(lambda x, w: _ragged(x, w, sizes), x, w)[1](g)
+
+
+_ragged_fwd = _batch_all(_ragged)
+_ragged_bwd = _batch_all(_ragged_vjp)
+
+
+@jax.custom_vjp
+def grouped_matmul(x, w, sizes):
+    """(m, k) rows sorted by group x (G, k, n) -> (m, n): row block g of
+    ``sizes[g]`` rows times ``w[g]``; rows past ``sum(sizes)`` are 0.
+
+    ``jax.lax.ragged_dot`` with its own gradient, made batchable (the
+    custom VJP keeps reverse mode under vmap, which a bare custom vmap
+    rule does not).
+    """
+    return _ragged_fwd(x, w, sizes)
+
+
+def _gm_fwd(x, w, sizes):
+    return _ragged_fwd(x, w, sizes), (x, w, sizes)
+
+
+def _gm_bwd(res, g):
+    x, w, sizes = res
+    dx, dw = _ragged_bwd(x, w, sizes, g)
+    return dx, dw, None
+
+
+grouped_matmul.defvjp(_gm_fwd, _gm_bwd)
+
+
+def moe_dropless_init(key, cfg):
+    """Router over ``cfg.num_router_experts`` experts, the weights of the
+    ``cfg.num_experts`` held here, and the shared expert (if any)."""
+    d, E, R = cfg.d_model, cfg.num_experts, cfg.num_router_experts
+    f, fs = cfg.d_expert or cfg.d_ff, cfg.d_shared_expert
+    dtype = jnp.dtype(cfg.dtype)
+    ks = jax.random.split(key, 6)
+    p = {
+        "router": dense_init(ks[0], d, R, jnp.float32, scale=0.02),
+        # The correction bias only steers selection; it gets no gradient
+        # and stays at its seeded value.
+        "router_bias": 0.01 * jax.random.normal(ks[1], (R,), jnp.float32),
+        "w_up": ((1.0 / math.sqrt(d)) * jax.random.normal(ks[2], (E, d, f))).astype(dtype),
+        "w_down": ((1.0 / math.sqrt(f)) * jax.random.normal(ks[3], (E, f, d))).astype(dtype),
+    }
+    if fs:
+        p["shared"] = {"w_up": dense_init(ks[4], d, fs, dtype),
+                       "w_down": dense_init(ks[5], fs, d, dtype)}
+    return p
+
+
+def route_sigmoid(params, cfg, xf):
+    """Top-k experts of every token over all router experts.
+
+    Returns (ids (T, k) int32, gates (T, k) f32): sigmoid scores, top-k
+    by score + correction bias, the chosen scores renormalised to sum 1
+    and times ``cfg.routed_scaling``.
+    """
+    scores = jax.nn.sigmoid(xf.astype(jnp.float32) @ params["router"])
+    bias = jax.lax.stop_gradient(params["router_bias"])
+    _, ids = jax.lax.top_k(scores + bias, cfg.num_experts_per_tok)
+    gates = jnp.take_along_axis(scores, ids, axis=-1)
+    gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
+    return ids, gates * cfg.routed_scaling
+
+
+def relu2_mlp(p, x):
+    """down(relu(x up)^2): Nemotron-H's ungated MLP."""
+    return jnp.square(jax.nn.relu(x @ p["w_up"])) @ p["w_down"]
+
+
+def moe_dropless(params, cfg, x, held=None, shared: bool = True):
+    """x (B, S, D) -> the held experts' gated part (+ the shared expert).
+
+    ``held`` lists the router ids of the experts whose weights are in
+    ``params`` (row e of ``w_up``/``w_down`` is expert ``held[e]``);
+    default ``0 .. num_experts - 1``. Every (token, choice) pair routed to
+    a held expert is computed — none is dropped — and pairs routed
+    elsewhere contribute nothing here (their holders compute them).
+    """
+    B, S, D = x.shape
+    xf = x.reshape(-1, D)
+    E = params["w_up"].shape[0]
+    k = cfg.num_experts_per_tok
+    held = jnp.arange(E) if held is None else jnp.asarray(held, jnp.int32)
+    with jax.named_scope("moe.route"):
+        ids, gates = route_sigmoid(params, cfg, xf)
+        hit = ids[..., None] == held  # (T, k, E)
+        local = jnp.where(hit.any(-1), jnp.argmax(hit, axis=-1), E).reshape(-1)
+        order = jnp.argsort(local, stable=True)
+        sizes = jnp.sum(jax.nn.one_hot(local, E, dtype=jnp.int32), axis=0)
+        tok = order // k
+        live = (local[order] < E)[:, None]
+    with jax.named_scope("moe.experts"):
+        # Rows past the held groups are left undefined by the TPU kernel,
+        # in the products and in their gradients: each is zeroed by a
+        # select (never a multiply) before anything reads it.
+        xs = jnp.where(live, xf[tok], 0.0)
+        h = jnp.where(live, grouped_matmul(xs, params["w_up"], sizes), 0.0)
+        h = jnp.square(jax.nn.relu(h)).astype(x.dtype)
+        ys = jnp.where(live, grouped_matmul(h, params["w_down"], sizes), 0.0)
+        ys = ys * gates.reshape(-1)[order][:, None]
+        y = jnp.zeros((xf.shape[0], D), jnp.float32).at[tok].add(ys)
+    if shared and "shared" in params:
+        with jax.named_scope("moe.shared"):
+            y = y + relu2_mlp(params["shared"], xf)
+    return y.astype(x.dtype).reshape(B, S, D)

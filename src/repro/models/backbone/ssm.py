@@ -10,10 +10,10 @@ is shared by every gated linear-attention family (Mamba2, mLSTM, GLA);
 quadratic *within* a chunk (MXU-friendly matmuls) and a ``lax.scan`` of
 states *across* chunks — O(S·C) instead of O(S²) work, O(S) memory.
 
-TPU adaptation (DESIGN.md §5): the chunk length is a multiple of the MXU
-tile (128) so the within-chunk matmuls are hardware-aligned, and the scan
-carries only the (H, dk, dv) state — it never materializes per-step decay
-products along the full sequence.
+TPU adaptation: the chunk length is a multiple of the MXU tile (128) so
+the within-chunk matmuls are hardware-aligned, and the scan carries only
+the (H, dk, dv) state — it never materializes per-step decay products
+along the full sequence.
 """
 from __future__ import annotations
 
@@ -140,21 +140,24 @@ def gla_decode_step(state, q, k, v, log_a):
 def mamba2_init(key, cfg):
     """Mamba2 block parameters.
 
-    d_inner = expand * d_model, H = d_inner / ssm_head_dim heads,
-    N = ssm_state. Single B/C group shared across heads (G=1), per-head
-    scalar A (the SSD restriction), depthwise conv of width ssm_conv over
-    the x/B/C streams, learned dt bias, and a gated RMSNorm before out-proj.
+    d_inner = ``cfg.ssm_inner`` (heads x head_dim, or expand * d_model),
+    H = d_inner / ssm_head_dim heads, N = ssm_state, G = ssm_groups B/C
+    groups (heads split evenly over them; zamba2 has one, shared by every
+    head), per-head scalar A (the SSD restriction), depthwise conv of
+    width ssm_conv over the x/B/C streams, learned dt bias, and a gated
+    RMSNorm (per group of d_inner / G channels) before out-proj.
     """
     d = cfg.d_model
-    d_inner = cfg.ssm_expand * d
+    d_inner = cfg.ssm_inner
     N = cfg.ssm_state
     H = d_inner // cfg.ssm_head_dim
-    conv_dim = d_inner + 2 * N
+    G = cfg.ssm_groups
+    conv_dim = d_inner + 2 * G * N
     dtype = jnp.dtype(cfg.dtype)
     ks = jax.random.split(key, 4)
     # in_proj emits [z (gate), x, B, C, dt] like the reference mamba2.
     return {
-        "in_proj": dense_init(ks[0], d, 2 * d_inner + 2 * N + H, dtype),
+        "in_proj": dense_init(ks[0], d, 2 * d_inner + 2 * G * N + H, dtype),
         "conv_w": (0.1 * jax.random.normal(ks[1], (cfg.ssm_conv, conv_dim))).astype(dtype),
         "conv_b": jnp.zeros((conv_dim,), dtype),
         "A_log": jnp.log(jnp.linspace(1.0, 16.0, H)).astype(jnp.float32),  # A = -exp(A_log)
@@ -167,11 +170,12 @@ def mamba2_init(key, cfg):
 
 def _mamba2_split(params, cfg, u):
     """Shared projection + causal conv. u: (B, S, D). Returns z, x, Bm, Cm, dt."""
-    d_inner = cfg.ssm_expand * cfg.d_model
+    d_inner = cfg.ssm_inner
     N = cfg.ssm_state
     H = d_inner // cfg.ssm_head_dim
-    proj = u @ params["in_proj"]  # (B,S,2*di+2N+H)
-    z, xBC, dt = jnp.split(proj, [d_inner, 2 * d_inner + 2 * N], axis=-1)
+    proj = u @ params["in_proj"]  # (B,S,2*di+2GN+H)
+    z, xBC, dt = jnp.split(
+        proj, [d_inner, 2 * d_inner + 2 * cfg.ssm_groups * N], axis=-1)
     return z, xBC, dt, d_inner, N, H
 
 
@@ -191,17 +195,38 @@ def _causal_conv(xBC, conv_w, conv_b, conv_state=None):
 def _mamba2_qkva(params, cfg, x_conv, dt_raw, d_inner, N, H):
     """Map conv output + dt to the GLA (q, k, v, log_a) views."""
     P = cfg.ssm_head_dim
-    x, Bm, Cm = jnp.split(x_conv, [d_inner, d_inner + N], axis=-1)
+    G = cfg.ssm_groups
+    x, Bm, Cm = jnp.split(x_conv, [d_inner, d_inner + G * N], axis=-1)
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + params["dt_bias"])  # (...,H)
     A = -jnp.exp(params["A_log"])  # (H,) negative
     log_a = dt * A  # (..., H)
     shape = x.shape[:-1]
     xh = x.reshape(*shape, H, P)
     v = xh * dt[..., None].astype(x.dtype)  # dt folds into v (SSD form)
-    # Single B/C group broadcast across heads.
-    k = jnp.broadcast_to(Bm[..., None, :], (*shape, H, N)).astype(x.dtype)
-    q = jnp.broadcast_to(Cm[..., None, :], (*shape, H, N)).astype(x.dtype)
+    # Head h reads B/C group h // (H / G) (one group: every head reads it).
+    k = _group_heads(Bm, G, H).astype(x.dtype)
+    q = _group_heads(Cm, G, H).astype(x.dtype)
     return q, k, v, log_a, xh
+
+
+def _group_heads(m, G: int, H: int):
+    """(..., G*N) -> (..., H, N): each group's N channels for its heads."""
+    shape = m.shape[:-1]
+    m = m.reshape(*shape, G, 1, m.shape[-1] // G)
+    return jnp.broadcast_to(m, (*shape, G, H // G, m.shape[-1])).reshape(
+        *shape, H, m.shape[-1])
+
+
+def _gated_norm(params, cfg, y, z):
+    """RMSNorm of y * silu(z) over each of the G groups of channels."""
+    g = y * jax.nn.silu(z)
+    G = cfg.ssm_groups
+    if G == 1:
+        return rmsnorm(g, params["out_norm"], cfg.norm_eps)
+    shape = g.shape[:-1]
+    g = g.reshape(*shape, G, g.shape[-1] // G)
+    w = params["out_norm"].reshape(G, -1)
+    return rmsnorm(g, w, cfg.norm_eps).reshape(*shape, -1)
 
 
 def _gla_dispatch(cfg, q, k, v, log_a):
@@ -210,26 +235,27 @@ def _gla_dispatch(cfg, q, k, v, log_a):
         from repro.kernels import ops as kops
 
         return kops.gla(q, k, v, log_a)
-    return chunked_gla(q, k, v, log_a)
+    return chunked_gla(q, k, v, log_a, chunk=cfg.ssm_chunk)
 
 
 def mamba2_block(params, cfg, u):
     """Full-sequence Mamba2 (train / prefill). u: (B, S, D) -> (B, S, D)."""
-    z, xBC, dt_raw, d_inner, N, H = _mamba2_split(params, cfg, u)
-    x_conv, _ = _causal_conv(xBC, params["conv_w"], params["conv_b"])
-    q, k, v, log_a, xh = _mamba2_qkva(params, cfg, x_conv, dt_raw, d_inner, N, H)
-    y = _gla_dispatch(cfg, q, k, v, log_a)
-    y = y + xh * params["D"][:, None].astype(xh.dtype)
-    y = y.reshape(*u.shape[:2], d_inner)
-    y = rmsnorm(y * jax.nn.silu(z), params["out_norm"], cfg.norm_eps)
-    return y @ params["out_proj"]
+    with jax.named_scope("mamba2"):
+        z, xBC, dt_raw, d_inner, N, H = _mamba2_split(params, cfg, u)
+        x_conv, _ = _causal_conv(xBC, params["conv_w"], params["conv_b"])
+        q, k, v, log_a, xh = _mamba2_qkva(params, cfg, x_conv, dt_raw,
+                                          d_inner, N, H)
+        y = _gla_dispatch(cfg, q, k, v, log_a)
+        y = y + xh * params["D"][:, None].astype(xh.dtype)
+        y = y.reshape(*u.shape[:2], d_inner)
+        return _gated_norm(params, cfg, y, z) @ params["out_proj"]
 
 
 def mamba2_init_cache(params, cfg, batch: int, dtype):
-    d_inner = cfg.ssm_expand * cfg.d_model
+    d_inner = cfg.ssm_inner
     N = cfg.ssm_state
     H = d_inner // cfg.ssm_head_dim
-    conv_dim = d_inner + 2 * N
+    conv_dim = d_inner + 2 * cfg.ssm_groups * N
     return {
         "conv": jnp.zeros((batch, cfg.ssm_conv - 1, conv_dim), dtype),
         "ssm": jnp.zeros((batch, H, N, cfg.ssm_head_dim), jnp.float32),
@@ -241,11 +267,11 @@ def mamba2_prefill(params, cfg, u):
     z, xBC, dt_raw, d_inner, N, H = _mamba2_split(params, cfg, u)
     x_conv, conv_state = _causal_conv(xBC, params["conv_w"], params["conv_b"])
     q, k, v, log_a, xh = _mamba2_qkva(params, cfg, x_conv, dt_raw, d_inner, N, H)
-    y = chunked_gla(q, k, v, log_a)
-    ssm_state = gla_final_state(k, v, log_a)
+    y = chunked_gla(q, k, v, log_a, chunk=cfg.ssm_chunk)
+    ssm_state = gla_final_state(k, v, log_a, chunk=cfg.ssm_chunk)
     y = y + xh * params["D"][:, None].astype(xh.dtype)
     y = y.reshape(*u.shape[:2], d_inner)
-    y = rmsnorm(y * jax.nn.silu(z), params["out_norm"], cfg.norm_eps)
+    y = _gated_norm(params, cfg, y, z)
     return y @ params["out_proj"], {"conv": conv_state, "ssm": ssm_state}
 
 
@@ -262,5 +288,5 @@ def mamba2_decode(params, cfg, u, cache):
     )
     y = y[:, None].astype(u.dtype) + xh * params["D"][:, None].astype(xh.dtype)
     y = y.reshape(u.shape[0], 1, d_inner)
-    y = rmsnorm(y * jax.nn.silu(z), params["out_norm"], cfg.norm_eps)
+    y = _gated_norm(params, cfg, y, z)
     return y @ params["out_proj"], {"conv": conv_state, "ssm": state}

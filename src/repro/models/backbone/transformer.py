@@ -49,7 +49,13 @@ from repro.models.backbone.layers import (
     rmsnorm,
     rmsnorm_init,
 )
-from repro.models.backbone.moe import moe_block, moe_block_dense, moe_init
+from repro.models.backbone.moe import (
+    moe_block,
+    moe_block_dense,
+    moe_dropless,
+    moe_dropless_init,
+    moe_init,
+)
 from repro.models.backbone.ssm import (
     mamba2_block,
     mamba2_decode,
@@ -78,8 +84,15 @@ PyTree = Any
 # ---------------------------------------------------------------------------
 
 def unit_structure(cfg: ArchConfig) -> Tuple[Tuple[str, ...], int, Tuple[str, ...]]:
-    """(unit_pattern, n_units, tail_pattern)."""
+    """(unit_pattern, n_units, tail_pattern).
+
+    A ``layer_pattern`` model has no repeating unit of equal blocks: every
+    block is its own layer, applied unrolled (and, in training, remat'd
+    one block at a time).
+    """
     pattern = cfg.block_pattern
+    if cfg.layer_pattern:
+        return (), 0, pattern
     period = cfg.hybrid_attn_period or cfg.slstm_period or 1
     if period <= 1:
         return (pattern[0],), len(pattern), ()
@@ -92,6 +105,12 @@ def unit_structure(cfg: ArchConfig) -> Tuple[Tuple[str, ...], int, Tuple[str, ..
 def _block_init(key, cfg: ArchConfig, kind: str, decoder: bool = False) -> PyTree:
     ks = jax.random.split(key, 4)
     dtype = jnp.dtype(cfg.dtype)
+    if kind == "moe":
+        return {"norm1": rmsnorm_init(cfg.d_model, dtype),
+                "moe": moe_dropless_init(ks[0], cfg)}
+    if kind == "attn" and cfg.layer_pattern:
+        return {"norm1": rmsnorm_init(cfg.d_model, dtype),
+                "attn": attn_init(ks[0], cfg)}
     if kind == "attn":
         if cfg.arch_type == "hybrid" and cfg.shared_attn:
             # Weights live in params["shared_attn"]; block carries only norms.
@@ -186,17 +205,23 @@ def _apply_block(
     """Returns (x, new_cache, aux_loss)."""
     aux = jnp.zeros((), jnp.float32)
     new_cache = cache
+    if kind == "moe":
+        h = rmsnorm(x, p["norm1"], cfg.norm_eps)
+        return x + moe_dropless(p["moe"], cfg, h), new_cache, aux
     if kind == "attn":
         attn_p = shared if (shared is not None) else p
         h = rmsnorm(x, p["norm1"], cfg.norm_eps)
-        if mode == "train":
-            y = attention_block(attn_p["attn"], cfg, h, positions, causal=causal)
-        elif mode == "prefill":
-            y, new_attn_cache = attention_prefill(attn_p["attn"], cfg, h, positions)
-        else:  # decode
-            y, new_attn_cache = attention_decode(
-                attn_p["attn"], cfg, h, cache["attn"], positions
-            )
+        with jax.named_scope("attn"):
+            if mode == "train":
+                y = attention_block(attn_p["attn"], cfg, h, positions,
+                                    causal=causal)
+            elif mode == "prefill":
+                y, new_attn_cache = attention_prefill(attn_p["attn"], cfg, h,
+                                                      positions)
+            else:  # decode
+                y, new_attn_cache = attention_decode(
+                    attn_p["attn"], cfg, h, cache["attn"], positions
+                )
         x = x + y
         if mode != "train":
             new_cache = dict(cache) if cache is not None else {}
@@ -240,6 +265,8 @@ def _apply_block(
 def _init_block_cache(params_block, cfg, kind, batch, max_len, dtype):
     if kind == "attn":
         return {"attn": init_kv_cache(cfg, batch, max_len, dtype)}
+    if kind == "moe":
+        return {}
     if kind == "mamba2":
         return mamba2_init_cache(params_block, cfg, batch, dtype)
     if kind == "mlstm":
@@ -280,7 +307,9 @@ def _apply_stack(params, cfg, x, positions, mode, caches, memory, remat=False):
             x = jax.lax.with_sharding_constraint(x, _P(None, "model", None))
         return x, out_caches, aux
 
-    if n_units == 1 or cfg.analysis_mode:
+    if n_units == 0:
+        pass
+    elif n_units == 1 or cfg.analysis_mode:
         # Unrolled path: exact per-layer FLOP counting for the roofline
         # analysis compiles (scan bodies are counted once by XLA cost
         # analysis), and trivially correct for single-unit stacks.
@@ -332,9 +361,13 @@ def _apply_stack(params, cfg, x, positions, mode, caches, memory, remat=False):
     for i, kind in enumerate(tail):
         c = (caches or {}).get("tail", {}).get(f"layer{i}") if caches else None
         sh = shared if (kind == "attn" and shared is not None) else None
-        x, nc, a = _apply_block(
-            params["tail"][f"layer{i}"], sh, cfg, kind, x, positions, mode, c, memory
-        )
+
+        def layer(p, x, c, sh=sh, kind=kind):
+            return _apply_block(p, sh, cfg, kind, x, positions, mode, c, memory)
+
+        if remat and cfg.layer_pattern:
+            layer = jax.checkpoint(layer)
+        x, nc, a = layer(params["tail"][f"layer{i}"], x, c)
         aux_total += a
         if nc is not None:
             new_caches["tail"][f"layer{i}"] = nc

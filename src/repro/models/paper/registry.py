@@ -362,3 +362,40 @@ def _build_multinomial(seed: int, num_silos: int, *, n_per: int = 60,
         num_obs=[len(p) for p in parts], eval_fn=eval_fn,
         extras={"model": model, "train_all": train_all, "test": test},
     )
+
+
+@register("nemotron_h",
+          "Nemotron-3-Nano hybrid Mamba-2 / MoE backbone under the Bayesian "
+          "LM head, next-token on Zipf-skewed synthetic silos")
+def _build_nemotron_h(seed: int, num_silos: int, *,
+                      seq_len: int = 32) -> ModelBundle:
+    """θ = the backbone (seeded random weights), Z_G / Z_Lj = the head's
+    global and per-silo adapters (``models/backbone/bayes.py``).
+
+    Builds the CPU-size variant of the config (its first pattern period
+    at small widths). Each silo holds 2 sequences of ``seq_len + 1``
+    tokens drawn from its own Zipf(1.1) over a silo-specific permutation
+    of the vocabulary, so silos route their tokens unevenly over the
+    experts.
+    """
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.configs import get_config
+    from repro.models.backbone import bayes, transformer
+
+    seqs_per_silo = 2
+    cfg = get_config("nemotron3-nano-30b-a3b").reduced()
+    V = cfg.vocab_size
+    rng = np.random.default_rng(seed)
+    p = 1.0 / np.arange(1, V + 1) ** 1.1
+    p /= p.sum()
+    datas = [{"tokens": jnp.asarray(rng.permutation(V)[rng.choice(
+        V, size=(seqs_per_silo, seq_len + 1), p=p)], jnp.int32)}
+        for _ in range(num_silos)]
+    theta0 = jax.jit(lambda k: transformer.init_params(k, cfg))(
+        jax.random.PRNGKey(seed))
+    return ModelBundle(
+        problem=bayes.sfvi_problem(cfg), theta0=theta0, datas=datas,
+        num_obs=[seqs_per_silo] * num_silos, extras={"arch": cfg})

@@ -68,7 +68,7 @@ def test_one_train_step(arch):
 @pytest.mark.parametrize(
     "arch",
     ["qwen3-4b", "zamba2-7b", "xlstm-1.3b", "olmoe-1b-7b", "qwen2-vl-2b",
-     "whisper-base"],
+     "whisper-base", "nemotron3-nano-30b-a3b"],
 )
 def test_prefill_decode_matches_forward(arch):
     cfg = get_config(arch).reduced()
@@ -122,6 +122,7 @@ def test_config_exact_dims(arch):
         "qwen2-vl-2b": (28, 1536, 12, 2, 8960, 151936),
         "xlstm-1.3b": (48, 2048, 4, 4, 0, 50304),
         "phi3.5-moe-42b-a6.6b": (32, 4096, 32, 8, 6400, 32064),
+        "nemotron3-nano-30b-a3b": (52, 2688, 32, 2, 1856, 131072),
     }[arch]
     cfg = get_config(arch)
     got = (cfg.num_layers, cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
@@ -137,6 +138,11 @@ def test_config_exact_dims(arch):
         assert (cfg.num_experts, cfg.num_experts_per_tok) == (16, 2)
     if arch == "xlstm-1.3b":
         assert cfg.slstm_period == 8
+    if arch == "nemotron3-nano-30b-a3b":
+        assert cfg.layer_pattern[:6] == "MEMEM*" and len(cfg.layer_pattern) == 52
+        assert (cfg.num_experts, cfg.num_experts_per_tok) == (128, 6)
+        assert (cfg.ssm_inner, cfg.ssm_groups, cfg.d_shared_expert) == (
+            4096, 8, 3712)
     if arch == "whisper-base":
         assert cfg.is_encoder_decoder and cfg.num_encoder_layers == 6
     if arch == "qwen2-vl-2b":
@@ -146,6 +152,9 @@ def test_config_exact_dims(arch):
 @pytest.mark.parametrize("arch", ARCH_NAMES)
 def test_reduced_within_limits(arch):
     r = get_config(arch).reduced()
-    assert r.num_layers <= 2
+    # A per-block pattern keeps its first whole period (MEMEM* for
+    # Nemotron-H) so that every block kind is present.
+    period = r.layer_pattern.index("*") + 1 if r.layer_pattern else 2
+    assert r.num_layers <= period
     assert r.d_model <= 512
     assert r.num_experts <= 4
