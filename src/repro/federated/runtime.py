@@ -59,7 +59,8 @@ zero wire bytes); ε_{L_j} additionally folds in the silo id.
 """
 from __future__ import annotations
 
-from typing import Any, Callable, Dict, Optional, Sequence, Union
+import dataclasses
+from typing import Any, Callable, Dict, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -85,7 +86,7 @@ from repro.federated.strategy import (
 )
 from repro.kernels import wire as wire_kernels
 from repro.federated.privacy import PrivacyPolicy, RdpAccountant
-from repro.federated.scheduler import RoundScheduler
+from repro.federated.scheduler import RoundScheduler, draw_masks
 from repro.launch.mesh import (
     MeshSpec,
     build_mesh,
@@ -216,6 +217,22 @@ def _wire_codec(comp) -> str:
     back to per-silo ``encode``/``decode`` around the same gather.
     """
     return getattr(comp, "wire_codec", "custom")
+
+
+@dataclasses.dataclass(frozen=True)
+class _RunSetup:
+    """What ``Server.run`` places once per Server, beside its program.
+
+    ``ctl`` holds the in-graph round's placed inputs (the base round key,
+    the schedule seed, the membership vector); None when the schedule is
+    drawn on the host. Per Server, not in the shared graph cache: the
+    arrays live on this Server's mesh and carry its seed.
+    """
+
+    up1: int  # upload bytes of one silo, one exchange
+    down1: int  # broadcast bytes of one silo, one exchange
+    n_j: jax.Array  # the placed per-silo observation counts
+    ctl: Optional[Dict[str, jax.Array]]
 
 
 class Server:
@@ -426,6 +443,9 @@ class Server:
         self.state["strategy"] = self._strategy.init_silo_state(self)
         self.place()
         self.comm = CommMeter()
+        # (r, the device scalar r) the last in-graph round handed back:
+        # the next round's index, already on the device.
+        self._next_round: Optional[Tuple[int, jax.Array]] = None
         # Shared across structurally-identical Servers (resume!) when the
         # builder hands in a token; private otherwise. See graph_cache.
         self._round_fns: Dict[tuple, Callable] = graph_cache.round_fns(
@@ -513,6 +533,10 @@ class Server:
             put = jax.device_put
         self.data = put(self.data, data_sh)
         self.state = {k: put(v, state_sh[k]) for k, v in self.state.items()}
+        # Every change of J or of the state's shapes ends here (growth,
+        # lazily created strategy state, warm starts, resume), and so
+        # drops ``run``'s cached set-up, which reads both.
+        self._setups: Dict[tuple, _RunSetup] = {}
 
     # -- silo-axis padding ---------------------------------------------------
 
@@ -805,6 +829,13 @@ class Server:
     # -- the compiled round --------------------------------------------------
 
     def _get_round(self, algorithm, local_steps: int) -> Callable:
+        """The compiled round of (strategy, K) at this ``J_pad``, its
+        control inputs (round key, mask, weights) explicit arguments.
+
+        The one source of the round body: ``run_buffered`` and schedules
+        drawn on the host launch it, and the in-graph round of
+        :meth:`_sync_round` traces it inline.
+        """
         strat = self._resolve(algorithm)
         strat.validate(self)
         self._ensure_strategy_state(strat)
@@ -888,6 +919,109 @@ class Server:
                 donate_argnums=(0,) if accumulate else (),
             )
         return self._round_fns[key]
+
+    def _sync_round(self, strat: ServerStrategy, local_steps: int,
+                    rule: tuple) -> Callable:
+        """The synchronous round, its control inputs drawn in the graph.
+
+        Cached per (strategy, K, J_pad, ``rule``) in ``_round_fns``,
+        beside the explicit-input round of :meth:`_get_round`, whose body
+        it traces inline: only this program compiles on the path. From
+        the absolute round index ``r``, a traced int32 scalar, it derives
+        what the host used to draw and send every round: the round key
+        ``fold_in(base_key, r)``; the E exchange masks of
+        :func:`~repro.federated.scheduler.draw_masks` under the static
+        ``rule``, at schedule indices ``r * K + t`` (step cadence) or
+        ``r``; their product with the membership and staleness vectors;
+        and the meter's per-exchange active and invited counts. It hands
+        back ``r + 1`` beside them, so a steady loop gives the next round
+        its index without a transfer.
+        """
+        key = (strat.cache_key(), local_steps, self.J_pad, rule)
+        if key in self._round_fns:
+            return self._round_fns[key]
+        body = self._get_round(strat, local_steps)
+        step_cadence = strat.cadence == "step"
+        j_pad = self.J_pad
+        cols = min(rule[0], j_pad)
+
+        def on_axis(m):
+            # The scheduler is roster-wide: its first columns are the
+            # stacked silos; the padded tail is never invited.
+            return jnp.pad(m[:, :cols], ((0, 0), (0, j_pad - cols)))
+
+        def round_fn(state, data, n_j, ctl):
+            r = ctl["round"]
+            # Step cadence gathers every local step, each gather its own
+            # draw (schedule index = exchange index), which the
+            # accountant's per-exchange amplification needs; round
+            # cadence gathers once, one draw a round.
+            idx = (r * local_steps + jnp.arange(local_steps, dtype=r.dtype)
+                   if step_cadence else r[None])
+            inv, rep = draw_masks(ctl["seed"], idx, rule)
+            inv, rep = on_axis(inv), on_axis(rep)
+            weights = rep * ctl["weight"]
+            inv, mask = inv * ctl["present"], rep * ctl["present"]
+            active = jnp.sum(mask, axis=1)
+            invited = jnp.maximum(jnp.sum(inv, axis=1), active)
+            if not step_cadence:
+                mask, weights = mask[0], weights[0]
+            round_key = jax.random.fold_in(ctl["key"], r)
+            state, metrics = body(state, data, n_j, round_key, mask, weights)
+            # What the host reads, as ONE array (one transfer): the ELBO
+            # trace, then the E active and E invited counts (small
+            # integers, exact in float32).
+            report = jnp.concatenate([metrics["elbo"], active, invited])
+            return state, {"report": report, "round": r + 1}
+
+        state_sh, data_sh = self._shardings()
+        rep_sh = NamedSharding(self.mesh, P())
+        self._round_fns[key] = jax.jit(
+            round_fn,
+            in_shardings=(state_sh, data_sh, rep_sh, rep_sh),
+            out_shardings=(state_sh, rep_sh),
+            donate_argnums=(0,) if self.accumulates(strat) else (),
+        )
+        return self._round_fns[key]
+
+    def _run_setup(self, strat: ServerStrategy, local_steps: int,
+                   sched) -> _RunSetup:
+        """``run``'s per-Server inputs for (strategy, K, J_pad, schedule).
+
+        A miss also builds the round program, so a hit finds it in
+        ``_round_fns``. ``place`` drops this cache, so growth and any
+        change of the state's shapes rebuild it; a hit transfers nothing.
+        """
+        rule = getattr(sched, "graph_rule", None)
+        key = (strat.cache_key(), local_steps, self.J_pad, rule,
+               None if rule is None else sched.seed)
+        setup = self._setups.get(key)
+        if setup is not None:
+            return setup
+        covers = getattr(sched, "num_silos", self.J)
+        if covers < self.J:
+            raise ValueError(f"the scheduler covers {covers} silos; the "
+                             f"federation has J={self.J}")
+        # Graph construction and byte metering evaluate wire templates
+        # eagerly on host, sanctioned under the transfer guard.
+        with debug.host_bridge():
+            self._get_round(strat, local_steps)  # may re-place
+            ctl = None
+            if rule is not None:
+                self._sync_round(strat, local_steps, rule)
+                members = np.zeros((self.J_pad,), np.float32)
+                members[: self.J] = 1.0
+                # repro-lint: allow[R1] — root of the round stream; the round program folds in the absolute round index, so resume replays it exactly
+                base_key = jax.random.PRNGKey(self.seed)
+                ctl = self._control({"key": base_key,
+                                     "seed": sched.seed_u32,
+                                     "present": members})
+                ctl["weight"] = ctl["present"]
+            setup = _RunSetup(self.bytes_up_per_silo(strat),
+                              self.bytes_down_per_silo(),
+                              self._control(self.num_obs), ctl)
+        self._setups[key] = setup
+        return setup
 
     def _ctx(self, K: int, wire) -> StrategyContext:
         """Static per-body facts handed to every strategy hook."""
@@ -1192,6 +1326,63 @@ class Server:
 
     # -- driver --------------------------------------------------------------
 
+    def _graph_control(self, setup: _RunSetup, r: int, present=None,
+                       stale_w=None) -> tuple:
+        """The in-graph round's control argument for absolute round ``r``.
+
+        In a steady loop nothing moves host to device: the round index
+        is the one the previous round handed back, and without a
+        population the membership vector is the cached one. A fresh
+        index (first call, resume, an explicit ``start_round``) and a
+        population's per-round membership and staleness vectors go over
+        in one transfer.
+        """
+        ctl = dict(setup.ctl)
+        sent: Dict[str, np.ndarray] = {}
+        if self._next_round is not None and self._next_round[0] == r:
+            ctl["round"] = self._next_round[1]
+        else:
+            sent["round"] = np.int32(r)
+        if present is not None:
+            pad = (0, self.J_pad - self.J)
+            sent["present"] = np.pad(present, pad)
+            sent["weight"] = np.pad(stale_w, pad)
+        if sent:
+            with debug.host_bridge():
+                ctl.update(self._control(sent))
+        return (ctl,)
+
+    def _host_control(self, sched, r: int, local_steps: int,
+                      step_cadence: bool, present, stale_w):
+        """Host-drawn control inputs, for a scheduler whose rule is host
+        code (it overrides ``round_masks``): the round's E exchange masks
+        in one draw and one pull, sent with the round key in one
+        transfer. Returns the round's arguments and the meter's
+        per-exchange (active, invited) counts."""
+        ex_idx = ([r * local_steps + t for t in range(local_steps)]
+                  if step_cadence else [r])
+        with debug.host_bridge():
+            inv, rep = jax.device_get(sched.round_masks(ex_idx))
+            if present is not None:
+                # The scheduler is roster-wide; slice to the joined J.
+                wts = rep[:, : self.J] * stale_w
+                rep = rep[:, : self.J] * present
+                inv = inv[:, : self.J] * present
+            else:
+                wts = rep
+            mask = np.zeros((len(ex_idx), self.J_pad), np.float32)
+            weights = np.zeros_like(mask)
+            mask[:, : self.J], weights[:, : self.J] = rep, wts
+            if not step_cadence:
+                mask, weights = mask[0], weights[0]
+            # repro-lint: allow[R1] — root of the round stream, folded with the absolute round index on the same line
+            round_key = jax.random.fold_in(jax.random.PRNGKey(self.seed), r)
+            args = self._control((round_key, mask, weights))
+        # Stragglers received the broadcast before dropping: bill their
+        # download; an absent silo receives no broadcast.
+        active = rep.sum(axis=1)
+        return args, (active, np.maximum(inv.sum(axis=1), active))
+
     def run(
         self,
         num_rounds: int,
@@ -1248,21 +1439,30 @@ class Server:
         in the aggregation weights. The scheduler stays roster-wide
         (its masks are sliced to the currently-joined J), so the
         participation schedule is independent of the churn schedule.
+
+        Control plane: a :class:`RoundScheduler` (any scheduler whose
+        ``graph_rule`` is set) is drawn inside the round program
+        (:meth:`_sync_round`), so a round costs the host one launch
+        and one pull (ELBO and the meter's counts), and in a steady loop
+        without a population nothing moves host to device. The set-up
+        (byte meter, placed inputs) is cached per (strategy, K, J_pad,
+        schedule) until :meth:`place`; the programs stay in the graph
+        cache.
+        A scheduler that overrides ``round_masks`` keeps the host draw:
+        one more pull and one transfer a round.
         """
         if local_steps < 1:
             raise ValueError(f"local_steps must be >= 1, got {local_steps}")
         strat = self._resolve(algorithm)
-        # One-time setup — graph construction and byte metering both
-        # evaluate wire templates eagerly on host, which is sanctioned
-        # under the transfer guard (repro.debug.host_bridge).
-        with debug.host_bridge():
-            fn = self._get_round(strat, local_steps)
-            up1 = self.bytes_up_per_silo(strat)
-            down1 = self.bytes_down_per_silo()
         # The default scheduler covers the FULL federation (fed_J == J
         # without a population): churn multiplies membership into the
         # roster-wide participation draws, it never re-shapes them.
         sched = scheduler or RoundScheduler(self.fed_J, seed=self.seed)
+        covers = getattr(sched, "num_silos", self.J)
+        if population is None and covers != self.J:
+            raise ValueError(f"the scheduler covers {covers} silos; the "
+                             f"federation has J={self.J}")
+        rule = getattr(sched, "graph_rule", None)
         step_cadence = strat.cadence == "step"
         exchanges = local_steps if step_cadence else 1
         history: Dict[str, list] = {
@@ -1275,66 +1475,42 @@ class Server:
             # (docs/privacy.md §Accounting); custom schedulers without a
             # participation attribute are accounted at full participation.
             q = float(getattr(sched, "participation", 1.0))
-        with debug.host_bridge():
-            # repro-lint: allow[R1] — root of the round stream; every key below folds in the absolute round index, so resume replays it exactly
-            base_key = jax.random.PRNGKey(self.seed)
-            n_j, n_j_for = self._control(self.num_obs), self.J
         for r in range(start_round, start_round + num_rounds):
-            # A step-cadence strategy synchronizes every local step, so
-            # each of the round's `exchanges` gathers is its OWN
-            # participation draw (schedule index = exchange index) —
-            # required for the accountant's per-exchange subsampling
-            # amplification to be sound. Round cadence gathers once:
-            # one draw per round.
-            ex_idx = ([r * local_steps + t for t in range(local_steps)]
-                      if step_cadence else [r])
-            # The control plane transfers tiny host values to device, so
-            # it runs in the sanctioned window (repro.debug.host_bridge);
-            # the ELBO pull below stays under the transfer guard.
-            with debug.host_bridge():
+            with jax.profiler.TraceAnnotation("server.round_control"):
                 present = stale_w = None
                 if population is not None:
                     # Churn first: a join may grow J (and step J_pad,
-                    # re-fetching the compiled round); the membership
-                    # and staleness vectors cover the post-growth J.
-                    present, stale_w = population.begin_round(self, r)
-                    fn = self._get_round(strat, local_steps)
-                    if self.J != n_j_for:
-                        n_j, n_j_for = self._control(self.num_obs), self.J
-                # The round's E exchange masks in one draw and one pull:
-                # (E, J) host copies that the meter counts and that ride
-                # the round in one transfer.
-                inv, rep = jax.device_get(sched.round_masks(ex_idx))
-                if present is not None:
-                    # The scheduler is roster-wide; slice to the joined J.
-                    wts = rep[:, : self.J] * stale_w
-                    rep = rep[:, : self.J] * present
-                    inv = inv[:, : self.J] * present
+                    # which drops the cached set-up); the membership and
+                    # staleness vectors cover the post-growth J.
+                    with debug.host_bridge():
+                        present, stale_w = population.begin_round(self, r)
+                setup = self._run_setup(strat, local_steps, sched)
+                fn = (self._get_round(strat, local_steps) if rule is None
+                      else self._sync_round(strat, local_steps, rule))
+                if setup.ctl is not None:
+                    args = self._graph_control(setup, r, present, stale_w)
                 else:
-                    wts = rep
-                mask = np.zeros((len(ex_idx), self.J_pad), np.float32)
-                weights = np.zeros_like(mask)
-                mask[:, : self.J], weights[:, : self.J] = rep, wts
-                if not step_cadence:
-                    mask, weights = mask[0], weights[0]
-                mask, weights = self._control((mask, weights))
-                round_key = jax.random.fold_in(base_key, r)
-                if self.n_processes > 1:
-                    round_key = self._control(round_key)
-            # Stragglers received the broadcast before dropping: bill
-            # their download; an absent silo receives no broadcast.
-            active = [int(a) for a in rep.sum(axis=1)]
-            invited = [max(int(i), a)
-                       for i, a in zip(inv.sum(axis=1), active, strict=True)]
-            # Sync rounds aggregate with the participation mask itself
-            # (population churn decays a returning silo's weight); the
-            # async engine passes staleness-decayed weights instead.
-            self.state, metrics = fn(self.state, self.data, n_j,
-                                     round_key, mask, weights)
-            elbos = jax.device_get(metrics["elbo"])
-            up = sum(active) * up1
-            down = sum(invited) * down1
-            n_active = active[-1]  # the round's final exchange
+                    args, counts = self._host_control(
+                        sched, r, local_steps, step_cadence, present,
+                        stale_w)
+            with jax.profiler.TraceAnnotation("server.round_launch"):
+                self.state, metrics = fn(self.state, self.data, setup.n_j,
+                                         *args)
+            # Explicit pulls, legal under the transfer guard: the in-graph
+            # round's counts ride with its ELBO in one.
+            with jax.profiler.TraceAnnotation("server.round_pull"):
+                if setup.ctl is not None:
+                    report = jax.device_get(metrics["report"])
+                    self._next_round = (r + 1, metrics["round"])
+                    elbos, active, invited = np.split(
+                        report, [report.size - 2 * exchanges,
+                                 report.size - exchanges])
+                else:
+                    elbos = jax.device_get(metrics["elbo"])
+                    active, invited = counts
+            up = int(active.sum()) * setup.up1
+            down = int(invited.sum()) * setup.down1
+            n_active = int(active[-1])  # the round's final exchange
             self.comm.record(up, down)
             history["elbo"].append(float(elbos[-1]))
             history["elbo_trace"].extend(float(e) for e in elbos)

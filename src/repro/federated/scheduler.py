@@ -5,8 +5,8 @@ reproduces the paper's Algorithms 1–2 exactly; ``participation < 1``
 samples a random subset per round (cross-device FL); ``dropout > 0``
 models stragglers that accept the round but fail to report back. Masks
 are deterministic functions of (seed, round index) so a schedule can be
-replayed — and so the compiled round function can take the mask as a
-plain (J,) array argument without retracing.
+replayed — and so ``Server.run``'s round program draws them in its own
+graph from a traced round index (:func:`draw_masks`).
 
 :class:`Scenario` bundles every orthogonal knob — sync cadence,
 participation, stragglers, wire compression, differential privacy —
@@ -70,18 +70,39 @@ class RoundScheduler:
     def round_masks(self, indices: Sequence[int]):
         """(invited, reported): two (E, J) float32 masks for E indices.
 
-        One jitted, vmapped draw per call (:func:`_draw`), so a round
-        of K synchronised exchanges costs one dispatch and one pull,
-        not K. Row ``e`` is the draw of schedule index ``indices[e]``:
+        One jitted, vmapped draw per call (:func:`draw_masks`), so a
+        round of K synchronised exchanges costs one dispatch, not K.
+        Row ``e`` is the draw of schedule index ``indices[e]``:
         ``invited`` is who the server broadcasts to, ``reported`` who
         uploads (stragglers are invited but absent).
         """
-        # PRNGKey reads its seed modulo 2**32; the uint32 keeps every
-        # seed in range of the traced argument.
-        return _draw(np.uint32(self.seed % 2 ** 32),
-                     np.asarray(indices, np.int32), J=self.num_silos,
-                     participation=float(self.participation),
-                     dropout=float(self.dropout))
+        return draw_masks(self.seed_u32, np.asarray(indices, np.int32),
+                          self._rule)
+
+    @property
+    def seed_u32(self) -> np.uint32:
+        """The schedule seed as :func:`draw_masks` takes it.
+
+        PRNGKey reads its seed modulo 2**32; the uint32 keeps every
+        seed in range of the traced argument.
+        """
+        return np.uint32(self.seed % 2 ** 32)
+
+    @property
+    def _rule(self) -> tuple:
+        return (self.num_silos, float(self.participation),
+                float(self.dropout))
+
+    @property
+    def graph_rule(self) -> Optional[tuple]:
+        """``(J, participation, dropout)``, the static half of the draw,
+        for a round program that runs :func:`draw_masks` in its own
+        graph from (seed, index) inputs; None when a subclass overrides
+        :meth:`round_masks`, whose rule is host code the graph cannot
+        reproduce."""
+        if type(self).round_masks is not RoundScheduler.round_masks:
+            return None
+        return self._rule
 
     def invited(self, round_idx: int) -> jnp.ndarray:
         """(J,) float32 mask of silos the server *broadcasts to* this round.
@@ -100,10 +121,16 @@ class RoundScheduler:
         return self.round_masks(range(num_rounds))[1]
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("J", "participation", "dropout"))
-def _draw(seed, indices, *, J: int, participation: float, dropout: float):
-    """The schedule rule, vmapped over schedule indices (see round_masks)."""
+@functools.partial(jax.jit, static_argnums=2)
+def draw_masks(seed, indices, rule: tuple):
+    """The schedule rule, vmapped over schedule indices (see round_masks).
+
+    ``seed`` (uint32) and ``indices`` (int32) may be traced: the round
+    program of ``Server.run`` calls this inside its own graph with the
+    absolute round index; ``rule`` is the static ``(J, participation,
+    dropout)``.
+    """
+    J, participation, dropout = rule
 
     def one(i):
         # repro-lint: allow[R1] — participation stream root, folded with the absolute schedule index on the same line

@@ -7,6 +7,8 @@ Covers the two correctness anchors from the paper:
     sizes and parameter-space averaging the round maps are identical.
 plus the aggregation/compression/scheduling plumbing.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -28,6 +30,7 @@ from repro.federated import (
     global_eps,
     silo_eps,
 )
+from repro.federated.strategy import get_strategy
 from repro.optim.adam import adam
 from repro.optim.sgd import sgd
 
@@ -347,6 +350,194 @@ class TestScheduling:
                     scheduler=RoundScheduler(4, participation=0.5, seed=1))
         assert all(n == 2 for n in h["n_active"])
         assert srv.comm.bytes_up < 10 * 4 * srv.bytes_up_per_silo("sfvi") + 1
+
+
+def _assert_same_run(h, h_ref, exp, ref):
+    """History and every state leaf bit-identical (NaN equal to NaN)."""
+    for k in ("elbo", "elbo_trace", "bytes_up", "bytes_down", "n_active"):
+        np.testing.assert_array_equal(np.asarray(h[k]), np.asarray(h_ref[k]),
+                                      err_msg=k)
+    leaves = jax.tree_util.tree_leaves(exp.state)
+    ref_leaves = jax.tree_util.tree_leaves(ref.state)
+    assert len(leaves) == len(ref_leaves)
+    for a, b in zip(leaves, ref_leaves):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+class TestInGraphRound:
+    """A RoundScheduler's draw, the round key and the meter's counts are
+    derived inside the round program from the absolute round index; the
+    eager host draw (EagerRoundScheduler, which overrides round_masks
+    and so keeps the host path) is the oracle, bit for bit."""
+
+    KNOBS = dict(participation=0.5, dropout=0.2, seed=2)
+
+    def _spec(self, algorithm, churn, rounds=7):
+        from repro.federated.api import ExperimentSpec, ModelSpec
+        from repro.federated.population import PopulationSpec
+        from repro.federated.scheduler import Scenario
+
+        return ExperimentSpec(
+            model=ModelSpec("toy"),
+            scenario=Scenario(algorithm=algorithm, participation=0.5,
+                              dropout=0.2),
+            num_silos=5, rounds=rounds, local_steps=3, seed=2,
+            population=(PopulationSpec(initial=2, arrival_rate=0.6,
+                                       departure_rate=0.2, return_rate=0.5,
+                                       seed=3) if churn else None))
+
+    @pytest.mark.parametrize("churn", [False, True])
+    @pytest.mark.parametrize("algorithm", ["sfvi", "sfvi_avg"])
+    def test_history_and_state_match_eager_oracle(self, algorithm, churn):
+        from repro.federated.api import build
+
+        spec = self._spec(algorithm, churn)
+        exp = build(spec)
+        assert exp.scheduler.graph_rule is not None
+        for n in (1, 2, None):  # ragged calls, as a user loop makes
+            h = exp.run(n)
+        ref = build(spec)
+        ref.scheduler = EagerRoundScheduler(5, **self.KNOBS)
+        assert ref.scheduler.graph_rule is None
+        _assert_same_run(h, ref.run(), exp.server, ref.server)
+        assert len(set(h["n_active"])) > 1
+
+    @pytest.mark.parametrize("algorithm", ["sfvi", "sfvi_avg"])
+    def test_split_runs_equal_one_run(self, algorithm):
+        """run(a); run(b, start_round=a) is run(a + b), and a jump to an
+        explicit start_round replays that round's draws, as the host
+        draw does."""
+        prob = _hier_problem()
+        theta = {"m": jnp.asarray(0.0), "lt": jnp.asarray(0.0)}
+        eta_G = prob.global_family.init(jax.random.PRNGKey(1))
+        datas = _datas(jax.random.PRNGKey(2), 4, 6, 2)
+
+        def server():
+            return Server(prob, datas, theta, eta_G, server_opt=adam(2e-2),
+                          local_opt=adam(2e-2), seed=9)
+
+        sched = RoundScheduler(4, **self.KNOBS)
+        kw = dict(algorithm=algorithm, local_steps=2)
+        split, whole = server(), server()
+        h = split.run(3, scheduler=sched, **kw)
+        h2 = split.run(2, scheduler=sched, start_round=3, **kw)
+        h_whole = whole.run(5, scheduler=sched, **kw)
+        _assert_same_run({k: h[k] + h2[k] for k in h}, h_whole, split, whole)
+
+        jump, oracle = server(), server()
+        eager = EagerRoundScheduler(4, **self.KNOBS)
+        h = jump.run(2, scheduler=sched, **kw)
+        h2 = jump.run(2, scheduler=sched, start_round=7, **kw)
+        o = oracle.run(2, scheduler=eager, **kw)
+        o2 = oracle.run(2, scheduler=eager, start_round=7, **kw)
+        _assert_same_run({k: h[k] + h2[k] for k in h},
+                         {k: o[k] + o2[k] for k in o}, jump, oracle)
+
+    @pytest.mark.parametrize("churn", [False, True])
+    def test_save_resume_equals_one_run(self, tmp_path, churn):
+        from repro.federated.api import Experiment, build
+
+        spec = self._spec("sfvi", churn, rounds=6)
+        whole = build(spec)
+        h_whole = whole.run()
+        exp = build(spec)
+        exp.run(3)
+        exp.save(str(tmp_path / "ckpt"))
+        resumed = Experiment.resume(str(tmp_path / "ckpt"))
+        h = resumed.run()
+        assert resumed.round == 6 and len(h["elbo"]) == 3
+        tail = {k: v[-len(h[k]):] for k, v in h_whole.items() if k in h}
+        _assert_same_run(h, tail, resumed.server, whole.server)
+
+    def test_setup_cache_follows_strategy_steps_and_growth(self):
+        """The cached set-up is keyed on strategy and K, and a join
+        that grows J_pad drops it; every phase matches the oracle."""
+        from repro.federated.api import build
+        from repro.federated.population import PopulationSpec
+
+        spec = dataclasses.replace(
+            self._spec("sfvi", churn=False),
+            population=PopulationSpec(initial=2, arrival_rate=1.0, seed=3))
+        exp, ref = build(spec), build(spec)
+        ref.scheduler = EagerRoundScheduler(5, **self.KNOBS)
+        pads, keys = [], []
+
+        def snap(r, m):
+            pads.append(exp.server.J_pad)
+            keys.append([k[:3] for k in exp.server._setups])
+
+        phases = (("sfvi", 3, 0, 2), ("sfvi", 2, 2, 1),
+                  ("sfvi_avg", 2, 3, 2), ("sfvi", 2, 5, 1))
+        runs = {id(exp): [], id(ref): []}
+        for algorithm, K, r0, n in phases:
+            for e in (exp, ref):
+                runs[id(e)].append(e.server.run(
+                    n, algorithm=algorithm, local_steps=K,
+                    scheduler=e.scheduler, start_round=r0,
+                    population=e.population,
+                    callback=snap if e is exp else None))
+
+        def joined(hs):
+            return {k: sum((h[k] for h in hs), []) for k in hs[0]}
+
+        _assert_same_run(joined(runs[id(exp)]), joined(runs[id(ref)]),
+                         exp.server, ref.server)
+        # One join a round on one device: J_pad steps 3, 4, 5 and each
+        # step drops the set-up; strategy and K key entries of their own,
+        # which a later run with the same pair finds again.
+        sfvi = get_strategy("sfvi")().cache_key()
+        avg = get_strategy("sfvi_avg")().cache_key()
+        assert pads == [3, 4, 5, 5, 5, 5], pads
+        assert keys == [[(sfvi, 3, 3)], [(sfvi, 3, 4)], [(sfvi, 2, 5)],
+                        [(sfvi, 2, 5), (avg, 2, 5)],
+                        [(sfvi, 2, 5), (avg, 2, 5)],
+                        [(sfvi, 2, 5), (avg, 2, 5)]], keys
+
+    def test_scheduler_overriding_round_masks_takes_effect(self):
+        """A subclass that overrides round_masks has no rule the graph
+        can trust: the Server keeps its host draw, and its masks rule
+        the round."""
+
+        class FirstSiloOnly(RoundScheduler):
+            def round_masks(self, indices):
+                rows = np.zeros((len(indices), self.num_silos), np.float32)
+                rows[:, 0] = 1.0
+                return rows, rows
+
+        sched = FirstSiloOnly(4, seed=2)
+        assert sched.graph_rule is None
+        assert RoundScheduler(4, seed=2).graph_rule == (4, 1.0, 0.0)
+        prob = _hier_problem()
+        srv = Server(prob, _datas(jax.random.PRNGKey(2), 4, 6, 2),
+                     {"m": jnp.asarray(0.0), "lt": jnp.asarray(0.0)},
+                     prob.global_family.init(jax.random.PRNGKey(1)),
+                     server_opt=adam(2e-2), local_opt=adam(2e-2))
+        eta_L0 = np.asarray(jax.tree_util.tree_leaves(srv.eta_L)[0])
+        h = srv.run(3, algorithm="sfvi", local_steps=2, scheduler=sched)
+        assert h["n_active"] == [1, 1, 1]
+        assert h["bytes_up"] == [2 * srv.bytes_up_per_silo("sfvi")] * 3
+        assert h["bytes_down"] == [2 * srv.bytes_down_per_silo()] * 3
+        eta_L = np.asarray(jax.tree_util.tree_leaves(srv.eta_L)[0])
+        assert (eta_L[0] != eta_L0[0]).any()  # the one reporter moved
+        np.testing.assert_array_equal(eta_L[1:], eta_L0[1:])  # the rest froze
+
+    @pytest.mark.parametrize("sched_cls", [RoundScheduler,
+                                           EagerRoundScheduler])
+    @pytest.mark.parametrize("width", [3, 8])
+    def test_scheduler_of_another_width_raises(self, sched_cls, width):
+        """Without a population the scheduler covers exactly the J
+        silos, whether it is drawn in the graph or on the host: a wider
+        one would invite a share of silos that do not exist."""
+        prob = _hier_problem()
+        srv = Server(prob, _datas(jax.random.PRNGKey(2), 4, 6, 2),
+                     {"m": jnp.asarray(0.0), "lt": jnp.asarray(0.0)},
+                     prob.global_family.init(jax.random.PRNGKey(1)),
+                     server_opt=adam(2e-2), local_opt=adam(2e-2))
+        sched = sched_cls(width, seed=2, participation=0.5)
+        with pytest.raises(ValueError, match="covers"):
+            srv.run(1, algorithm="sfvi", local_steps=2, scheduler=sched)
+        assert srv.run(1, algorithm="sfvi", local_steps=2,
+                       scheduler=sched_cls(4, seed=2))["n_active"] == [4]
 
 
 class TestCommAccounting:

@@ -308,17 +308,20 @@ _GROWTH_SCRIPT = textwrap.dedent("""
     pads, fns = [], []
     def snap(r, m):
         pads.append(full.server.J_pad)
-        fns.append(len(full.server._round_fns))
+        fns.append(sorted({k[2] for k in full.server._round_fns}))
     h_full = full.run(callback=snap)
     assert full.server.J == 5, full.server.J
     # Joins fire BEFORE their round, so the post-round snapshots see J
     # walk 3,4,5,5 — J_pad grows in mesh-sized (2) chunks...
     assert pads == [4, 4, 6, 6], pads
     # ...and the compiled round is refetched EXACTLY when J_pad steps:
-    # the round-fn cache holds one entry per distinct J_pad seen (2
-    # pre-join, then 4, then 6), none added within a chunk (round 1:
-    # J 3->4 inside the 4-chunk, no new entry).
-    assert fns == [2, 2, 3, 3], fns
+    # the round-fn cache holds the programs of each J_pad seen (4, then
+    # 6), none added within a chunk (round 1: J 3->4 inside the
+    # 4-chunk). Churn runs before a round's set-up, so the pre-join
+    # J_pad of 2 is never compiled; each J_pad holds the explicit-input
+    # round and the in-graph-draw round that traces its body.
+    assert fns == [[4], [4], [4, 6], [4, 6]], fns
+    assert len(full.server._round_fns) == 4
     print("chunked-growth OK")
 
     # Resume saved at J=4 (J_pad=4) re-grows past the 6-boundary

@@ -152,12 +152,15 @@ class TestTreeBytes:
 
 
 class TestControlPlane:
-    """Server.run's host control plane: one mask draw and one pull per
-    round, metered from the host copy."""
+    """Server.run's host control plane: a RoundScheduler's masks, the
+    round key and the meter's counts come out of the round program, so
+    a round is one launch and one pull."""
 
     @pytest.mark.parametrize("K,churn", [(1, False), (25, False), (3, True)])
     def test_two_pulls_per_round_and_history_matches_eager_masks(
             self, monkeypatch, K, churn):
+        """One pull a round; the pulled counts, the sent membership
+        vectors and the history are the eager oracle's."""
         from test_federated import EagerRoundScheduler
 
         from repro.federated import runtime
@@ -190,8 +193,8 @@ class TestControlPlane:
             return device_get(x)
 
         def keep_sent(host):
-            if isinstance(host, tuple):  # the round's (mask, weights)
-                sent.append(host)
+            if isinstance(host, dict) and "weight" in host:
+                sent.append(host)  # a population round's membership
             return control(host)
 
         monkeypatch.setattr(runtime.jax, "device_get", count_pull)
@@ -207,24 +210,32 @@ class TestControlPlane:
             monkeypatch.setattr(exp.population, "begin_round", keep_begun)
         exp.run(rounds=1)  # compiles; counted like any other round
         h = exp.run()
-        assert len(pulls) == 2 * R
+        assert len(pulls) == R
         assert h["elbo_trace"] == h_ref["elbo_trace"]
+        for a, b in zip(jax.tree_util.tree_leaves(exp.server.state),
+                        jax.tree_util.tree_leaves(ref.server.state)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        assert len(sent) == (R if churn else 0)
 
-        # The meter and the round's inputs, rebuilt from the eager draw.
+        # The round's counts, the meter and the sent membership, rebuilt
+        # from the eager draw.
         up1 = exp.server.bytes_up_per_silo()
         down1 = exp.server.bytes_down_per_silo()
         for r in range(R):
             inv, rep = eager.round_masks(range(r * K, (r + 1) * K))
             present, stale_w = begun[r] if churn else (np.ones(J),) * 2
             j = len(present)
-            inv, wts, rep = (inv[:, :j] * present, rep[:, :j] * stale_w,
-                             rep[:, :j] * present)
-            mask, weights = sent[r]
-            np.testing.assert_array_equal(mask[:, :j], rep)
-            np.testing.assert_array_equal(weights[:, :j], wts)
-            assert not mask[:, j:].any() and not weights[:, j:].any()
+            inv, rep = inv[:, :j] * present, rep[:, :j] * present
+            if churn:
+                np.testing.assert_array_equal(sent[r]["present"][:j], present)
+                np.testing.assert_array_equal(sent[r]["weight"][:j], stale_w)
+                assert not sent[r]["present"][j:].any()
+                assert not sent[r]["weight"][j:].any()
             active = rep.sum(axis=1).astype(int)
             invited = np.maximum(inv.sum(axis=1).astype(int), active)
+            report = np.asarray(pulls[r])  # ELBOs, then E active, E invited
+            np.testing.assert_array_equal(report[-2 * K:-K], active)
+            np.testing.assert_array_equal(report[-K:], invited)
             assert h["bytes_up"][r] == active.sum() * up1
             assert h["bytes_down"][r] == invited.sum() * down1
             assert h["n_active"][r] == active[-1]
@@ -233,3 +244,65 @@ class TestControlPlane:
             assert exp.population.state.joined > 2
             assert any((p == 0).any() for p, _ in begun)
             assert any(((w > 0) & (w < 1)).any() for _, w in begun)
+
+    def test_round_index_is_traced(self):
+        """20 rounds, one Experiment.run(rounds=1) call each, lower the
+        round program once: the absolute round index is a traced
+        argument, not a static one."""
+        from repro import debug
+        from repro.federated.api import ExperimentSpec, ModelSpec, build
+        from repro.federated.scheduler import Scenario
+
+        lowered = []
+
+        def listen(event, duration, **kw):
+            if event.endswith("jaxpr_to_mlir_module_duration"):
+                lowered.append(kw.get("fun_name"))
+
+        exp = build(ExperimentSpec(
+            model=ModelSpec("toy"),
+            scenario=Scenario(algorithm="sfvi", participation=0.5,
+                              dropout=0.2),
+            num_silos=4, rounds=20, local_steps=3, seed=5))
+        jax.monitoring.register_event_duration_secs_listener(listen)
+        try:
+            with debug.watch_recompiles() as wd:
+                for _ in range(20):
+                    exp.run(rounds=1)
+        finally:
+            jax.monitoring.unregister_event_duration_listener(listen)
+        assert lowered.count("jit(round_fn)") == 1, lowered
+        assert wd.total == 1, dict(wd.counts)
+        assert exp.round == 20 and len(exp.history["elbo"]) == 20
+
+    @pytest.mark.parametrize("algorithm", ["sfvi", "sfvi_avg"])
+    def test_steady_loop_transfers_nothing_to_the_device(
+            self, monkeypatch, algorithm):
+        """After the first round, with no population, a round moves
+        nothing host to device, explicitly or implicitly, and opens no
+        sanctioned host window."""
+        from repro import debug
+        from repro.federated import runtime
+        from repro.federated.api import ExperimentSpec, ModelSpec, build
+        from repro.federated.scheduler import Scenario
+
+        exp = build(ExperimentSpec(
+            model=ModelSpec("toy"),
+            scenario=Scenario(algorithm=algorithm, participation=0.5,
+                              dropout=0.2),
+            num_silos=4, rounds=6, local_steps=2, seed=1))
+        exp.run(rounds=1)
+        bridges = []
+        real = debug.host_bridge
+
+        def counted():
+            bridges.append(1)
+            return real()
+
+        monkeypatch.setattr(runtime.debug, "host_bridge", counted)
+        with jax.transfer_guard_host_to_device("disallow_explicit"):
+            for _ in range(5):
+                exp.run(rounds=1)
+        assert not bridges
+        assert len(exp.history["elbo"]) == 6
+        assert np.isfinite(exp.history["elbo"]).all()
