@@ -344,9 +344,9 @@ def smoke(json_path: str | None = None, seed: int | None = None) -> dict:
     _, scenarios[fused_sc.name + " [wire=fused]"] = _timed_row(
         fused_sc, wire="fused")
 
-    # Wire layouts head to head: the flat (J, P) relayout vs the fused
-    # Pallas kernels vs the per-leaf legacy reference — same config,
-    # same bundle, timed back to back (median of per-round ratios
+    # The two upload paths head to head: the flat (J, P) matrix through
+    # jnp ops vs the same matrix through the fused Pallas kernels — same
+    # config, same bundle, timed back to back (median of per-round ratios
     # against the interleaved yardstick, like the gated rows), plus the
     # roofline terms of each compiled round (HBM bytes moved is what
     # the fused kernels attack). Reported for visibility — the gated
@@ -354,7 +354,7 @@ def smoke(json_path: str | None = None, seed: int | None = None) -> dict:
     wire_compare = {}
     for sc in (SMOKE_SCENARIOS[1], SMOKE_SCENARIOS[2]):
         per, roofline = {}, {}
-        for layout in ("flat", "fused", "legacy"):
+        for layout in ("flat", "fused"):
             exp = staged_experiment(
                 cfg["model"], bundle, scenario=sc, num_silos=cfg["silos"],
                 rounds=cfg["rounds"], local_steps=cfg["local_steps"],
@@ -373,7 +373,6 @@ def smoke(json_path: str | None = None, seed: int | None = None) -> dict:
                 sc.algorithm, cfg["local_steps"])
         wire_compare[sc.name] = {
             **per,
-            "flat_speedup": per["legacy"] / per["flat"],
             "fused_speedup": per["flat"] / per["fused"],
             "roofline": roofline,
         }
@@ -446,19 +445,17 @@ def smoke(json_path: str | None = None, seed: int | None = None) -> dict:
                "calibrated_round", "compile_s", "sim_seconds", "epsilon"],
     )
     print_table(
-        "wire layout: fused Pallas vs flat (J, P) vs legacy per-leaf "
+        "wire: fused Pallas vs flat (J, P) "
         "(calibrated s/round; MB = bytes accessed per compiled round)",
         [{"Scenario": name,
           "wire=fused": round(r["fused"], 4),
           "wire=flat": round(r["flat"], 4),
-          "wire=legacy": round(r["legacy"], 4),
           "fused speedup": f"x{r['fused_speedup']:.2f}",
-          "flat speedup": f"x{r['flat_speedup']:.2f}",
           "fused MB": round(r["roofline"]["fused"]["bytes_accessed"] / 1e6, 2),
           "flat MB": round(r["roofline"]["flat"]["bytes_accessed"] / 1e6, 2)}
          for name, r in wire_compare.items()],
-        ["Scenario", "wire=fused", "wire=flat", "wire=legacy",
-         "fused speedup", "flat speedup", "fused MB", "flat MB"],
+        ["Scenario", "wire=fused", "wire=flat",
+         "fused speedup", "fused MB", "flat MB"],
     )
     print_table(
         "server strategies head to head (round cadence, wire=flat; "

@@ -76,7 +76,7 @@ def federated_posterior_row(yardstick=None) -> dict:
     row = {
         "elbo": float(hist["elbo"][-1]),
         "bytes_per_round": float(
-            NoCompression().wire_bytes(refresh, wire="flat")),
+            NoCompression().wire_bytes(refresh)),
         "s_per_round": lat,
         "sim_seconds": 0.0,
         "epsilon": None,
@@ -130,8 +130,8 @@ def run(quick: bool = True) -> dict:
         sync_tree = {"theta": state.theta, "eta_G": state.eta_G}
         # The federated sync ships over the flat (J, P) wire: one int8
         # payload + ONE f32 scale per silo, not one scale per leaf.
-        raw_b = NoCompression().wire_bytes(sync_tree, wire="flat")
-        int8_b = Int8Compressor().wire_bytes(sync_tree, wire="flat")
+        raw_b = NoCompression().wire_bytes(sync_tree)
+        int8_b = Int8Compressor().wire_bytes(sync_tree)
         rows.append({
             "arch": cfg.name,
             "prefill tok/s": f"{B * P / t_pre:.0f}",

@@ -28,16 +28,6 @@ from repro.core.barycenter import (
     sqrtm_newton_schulz,
     wasserstein2_gaussian,
 )
-from repro.core.runtime import (
-    CommLog,
-    SFVIAvgServer,
-    SFVIServer,
-    Silo,
-    tree_add,
-    tree_bytes,
-    tree_mean,
-    tree_scale,
-)
 
 __all__ = [
     "FAMILIES",
@@ -67,12 +57,4 @@ __all__ = [
     "sqrtm_eigh",
     "sqrtm_newton_schulz",
     "wasserstein2_gaussian",
-    "CommLog",
-    "SFVIAvgServer",
-    "SFVIServer",
-    "Silo",
-    "tree_add",
-    "tree_bytes",
-    "tree_mean",
-    "tree_scale",
 ]

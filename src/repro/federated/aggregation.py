@@ -22,14 +22,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.federated.metering import is_array, tree_bytes
+from repro.federated.metering import is_array
 
 PyTree = Any
-
-
-def _check_wire(wire: str) -> None:
-    if wire not in ("flat", "fused", "legacy"):
-        raise ValueError(f"unknown wire layout {wire!r} (flat/fused/legacy)")
 
 
 def _tree_elements(tree: PyTree) -> int:
@@ -151,20 +146,12 @@ class NoCompression:
         """Identity inverse of :meth:`encode`."""
         return enc
 
-    def wire_bytes(self, tree: PyTree, wire: str = "legacy") -> int:
-        """Wire size of the raw upload for the given wire layout.
-
-        ``legacy`` ships the pytree leaf-by-leaf at native dtypes —
-        delegates to :func:`repro.federated.metering.tree_bytes`, the
-        repo's single byte-accounting primitive. ``flat``/``fused``
-        pack the whole tree into ONE contiguous float32 vector
-        (:class:`~repro.core.flatten.TreeSpec`), so the wire carries
-        4 bytes per element regardless of leaf dtypes.
-        """
-        _check_wire(wire)
-        if wire in ("flat", "fused"):
-            return 4 * _tree_elements(tree)
-        return tree_bytes(tree)
+    def wire_bytes(self, tree: PyTree) -> int:
+        """Wire size of the raw upload: the runtime packs the whole tree
+        into ONE contiguous float32 row
+        (:class:`~repro.core.flatten.TreeSpec`), so the wire carries 4
+        bytes per element regardless of leaf dtypes."""
+        return 4 * _tree_elements(tree)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -197,25 +184,12 @@ class Int8Compressor:
         leaves = [d["q"].astype(jnp.float32) * d["scale"] for d in enc["leaves"]]
         return jax.tree_util.tree_unflatten(enc["treedef"].value, leaves)
 
-    def wire_bytes(self, tree: PyTree, wire: str = "legacy") -> int:
-        """Wire size of the quantized upload for the given wire layout.
-
-        ``legacy`` quantizes leaf-by-leaf: 1 B/element + one 4-byte f32
-        scale PER LEAF. ``flat``/``fused`` pack the whole upload into a
-        single (P,) vector first, so the silo ships one int8 row and
-        exactly ONE scale: P + 4 bytes. Billing the per-leaf scales on
-        the flat wire over-billed multi-leaf models relative to what
-        the compiled collective actually gathers (one s8 payload + one
-        f32 scale per silo — see ``launch.roofline.collective_bytes``).
-        """
-        _check_wire(wire)
-        n = _tree_elements(tree)
-        if wire in ("flat", "fused"):
-            return n + 4  # one int8 payload row + ONE f32 scale per silo
-        n_leaves = sum(
-            1 for x in jax.tree_util.tree_leaves(tree) if is_array(x)
-        )
-        return n + 4 * n_leaves
+    def wire_bytes(self, tree: PyTree) -> int:
+        """Wire size of the quantized upload: the runtime packs it into a
+        single (P,) row first, so the silo ships one int8 row and
+        exactly ONE f32 scale, P + 4 bytes — what the compiled collective
+        gathers (see ``launch.roofline.collective_bytes``)."""
+        return _tree_elements(tree) + 4
 
 
 @dataclasses.dataclass(frozen=True)

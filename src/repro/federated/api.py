@@ -28,9 +28,7 @@ object:
 
 This is the single construction path the CLI
 (``python -m repro.federated.run``), the examples, and the benchmark
-suite all build on; the legacy eager ``SFVIServer``/``SFVIAvgServer``
-are deprecated adapters over the same compiled runtime. See
-``docs/api.md``.
+suite all build on. See ``docs/api.md``.
 """
 from __future__ import annotations
 
@@ -38,7 +36,6 @@ import contextlib
 import dataclasses
 import json
 import os
-import warnings
 from typing import Any, Callable, Dict, List, Optional, Union
 
 import jax
@@ -55,22 +52,6 @@ from repro.launch.mesh import MeshSpec, build_mesh
 PyTree = Any
 
 _SPEC_FILE = "spec.json"
-
-# The deprecated out-of-band wire kwarg warns ONCE per process — sweeps
-# over many specs shouldn't drown their output in repeats.
-_WIRE_KWARG_WARNED = False
-
-
-def _warn_wire_kwarg(where: str) -> None:
-    global _WIRE_KWARG_WARNED
-    if not _WIRE_KWARG_WARNED:
-        warnings.warn(
-            f"the wire= kwarg on {where} is deprecated; set it on the spec "
-            "instead: ExperimentSpec(runtime=RuntimeSpec(wire=...)). The "
-            "kwarg still overrides the spec for now.",
-            DeprecationWarning, stacklevel=3)
-        _WIRE_KWARG_WARNED = True
-
 
 # ---------------------------------------------------------------------------
 # Spec tree
@@ -150,15 +131,13 @@ class ModelSpec:
 class RuntimeSpec:
     """Execution topology and wire layout — spec-carried, JSON-native.
 
-    Historically the wire layout rode an out-of-band ``wire=`` kwarg on
-    :func:`build` and the mesh was whatever ``make_silo_mesh`` decided;
-    both now live on the spec so a run's topology serializes, resumes
-    and sweeps like every other knob.
+    Both live on the spec so a run's topology serializes, resumes and
+    sweeps like every other knob.
 
     Attributes:
-      wire: silo→server wire layout — ``"flat"`` (packed (J, P)
-        matrix, the default), ``"fused"`` (same layout, Pallas-kernel
-        pipeline) or ``"legacy"`` (per-leaf reference).
+      wire: how the silo→server upload runs over the packed (J, P)
+        matrix — ``"flat"`` (jnp ops, the default) or ``"fused"`` (the
+        same layout through the Pallas wire kernels).
       mesh: the federated mesh topology
         (:class:`~repro.launch.mesh.MeshSpec`): ``silo`` devices × a
         ``model`` axis sharding each row's P wire parameters, plus the
@@ -173,9 +152,9 @@ class RuntimeSpec:
     sanitize: bool = False
 
     def __post_init__(self):
-        if self.wire not in ("flat", "fused", "legacy"):
+        if self.wire not in ("flat", "fused"):
             raise ValueError(
-                f"unknown wire layout {self.wire!r} (flat/fused/legacy)")
+                f"unknown wire layout {self.wire!r} (flat/fused)")
 
     @classmethod
     def from_dict(cls, d: Dict[str, Any]) -> RuntimeSpec:
@@ -315,8 +294,7 @@ class ExperimentSpec:
 # ---------------------------------------------------------------------------
 
 
-def build(spec: ExperimentSpec, bundle=None, *,
-          wire: Optional[str] = None) -> Experiment:
+def build(spec: ExperimentSpec, bundle=None) -> Experiment:
     """Assemble the compiled runtime for ``spec``.
 
     Resolves the model through the registry (unless a pre-staged
@@ -326,14 +304,8 @@ def build(spec: ExperimentSpec, bundle=None, *,
     optimizers, aggregation, compression, the privacy policy AND the
     execution topology — wire layout and federated mesh, from
     ``spec.runtime`` — and returns a ready-to-run :class:`Experiment`.
-
-    ``wire`` is a DEPRECATED override of ``spec.runtime.wire`` (warns
-    once); topology belongs on the spec so it serializes and resumes
-    with everything else.
     """
-    if wire is not None:
-        _warn_wire_kwarg("build()")
-    return _build(spec, bundle, wire)
+    return _build(spec, bundle)
 
 
 def _bundle_num_obs(bundle) -> List[int]:
@@ -345,9 +317,8 @@ def _bundle_num_obs(bundle) -> List[int]:
 
 
 def _build(spec: ExperimentSpec, bundle=None,
-           wire: Optional[str] = None,
            joined: Optional[int] = None) -> Experiment:
-    """The warning-free core of :func:`build` (resume calls this).
+    """:func:`build` with the resume path's population head-count.
 
     ``joined`` is the resume path's population head-count: a churn
     run's Server is built with exactly the silos that had joined at
@@ -359,7 +330,7 @@ def _build(spec: ExperimentSpec, bundle=None,
     from repro.federated.runtime import Server
     from repro.models.paper.registry import apply_family_spec, get_model
 
-    wire = wire if wire is not None else spec.runtime.wire
+    wire = spec.runtime.wire
     spec.scenario.validate(spec.num_silos)
     strat_spec = (spec.strategy if spec.strategy is not None
                   else StrategySpec(spec.scenario.algorithm))
@@ -617,9 +588,8 @@ class Experiment:
         meta: Dict[str, Any] = {
             "round": self.round,
             "comm": self.comm.state_dict(),
-            # The wire layout is an execution knob, not spec state — but
-            # DP noise keys and int8 scales depend on it, so a resume
-            # must rebuild with the SAME layout to stay bit-exact.
+            # The run's wire layout: resume reads it before building the
+            # Server, and refuses a layout this program does not run.
             "wire": self.server.wire,
         }
         if self.accountant is not None:
@@ -744,8 +714,7 @@ class Experiment:
 
     @classmethod
     def resume(cls, directory: str, spec: Optional[ExperimentSpec] = None,
-               step: Optional[int] = None, bundle=None,
-               wire: Optional[str] = None) -> Experiment:
+               step: Optional[int] = None, bundle=None) -> Experiment:
         """Rebuild from ``directory`` and restore the saved round state.
 
         Reads ``spec.json`` (unless ``spec`` overrides it), rebuilds the
@@ -756,13 +725,12 @@ class Experiment:
         checkpoint. Continuing with :meth:`run` reproduces the
         uninterrupted run bit-exactly.
 
-        ``wire`` is a DEPRECATED override (warns once; prefer
-        ``spec.runtime.wire``) of the checkpoint's recorded layout —
-        switching between ``"flat"`` and ``"fused"`` mid-run is safe
-        (the fused kernels replay the identical op sequence and DP
-        noise stream, so the continued trajectory is unchanged);
-        switching to/from ``"legacy"`` changes per-leaf DP fold-ins and
-        int8 scale granularity and will diverge under DP/compression.
+        The wire layout is the checkpoint's recorded one, else the
+        spec's; a caller-supplied ``spec`` chooses it instead, as it
+        chooses the mesh. Switching between ``"flat"`` and ``"fused"``
+        mid-run is safe (the fused kernels replay the identical op
+        sequence and DP noise stream, so the continued trajectory is
+        unchanged). A checkpoint recording any other layout is refused.
 
         A resume may land on a DIFFERENT topology than the run that
         saved (device count, ``MeshSpec`` shape, process count):
@@ -772,8 +740,7 @@ class Experiment:
         every process calls this; each reads only the silo shards it
         owns on the new mesh.
         """
-        if wire is not None:
-            _warn_wire_kwarg("Experiment.resume()")
+        chosen = spec is not None
         if spec is None:
             spec = ExperimentSpec.load(os.path.join(directory, _SPEC_FILE))
         mgr = CheckpointManager(directory)
@@ -781,15 +748,16 @@ class Experiment:
             step = mgr.latest_step()
         if step is None:
             raise FileNotFoundError(f"no checkpoints under {directory!r}")
-        # Meta first: the run's wire layout must be restored before the
-        # Server is built (DP keys / int8 scales are layout-dependent,
-        # so resuming a wire='legacy' run as 'flat' would diverge).
+        # Meta first: the run's wire layout must be known before the
+        # Server is built.
         with open(cls._meta_path(directory, step)) as f:
             meta = json.load(f)
+        recorded = dataclasses.replace(  # RuntimeSpec validates it
+            spec.runtime, wire=meta.get("wire", spec.runtime.wire))
+        if not chosen:
+            spec = dataclasses.replace(spec, runtime=recorded)
         pop_meta = meta.get("population")
         exp = _build(spec, bundle,
-                     wire if wire is not None
-                     else meta.get("wire", spec.runtime.wire),
                      joined=(int(pop_meta["joined"])
                              if pop_meta is not None else None))
         if exp.population is not None and pop_meta is not None:

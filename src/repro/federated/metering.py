@@ -1,10 +1,10 @@
 """Byte accounting for federated exchanges — the single metering path.
 
-Leaf module (imports only jax/numpy) so both runtimes can share it: the
-compiled :class:`~repro.federated.runtime.Server` bills its rounds into a
-:class:`CommMeter`, and the deprecated eager adapters in
-``repro.core.runtime`` alias it as ``CommLog``. :func:`tree_bytes` is the
-one primitive every byte figure in the repo is computed with.
+Leaf module (imports only jax/numpy): the compiled
+:class:`~repro.federated.runtime.Server` and the backbone driver
+(``launch/train.py``) bill their rounds into a :class:`CommMeter`.
+:func:`tree_bytes` is the one primitive every native-dtype byte figure
+in the repo is computed with.
 
 The meter is topology-independent: it bills ALGORITHM-level bytes
 (what each silo ships), so its figures are identical on a 1-device

@@ -22,8 +22,8 @@ All methods are pure jax functions over ANY pytree: the runtime's flat
 wire format hands the mechanism one packed ``(P,)`` vector per silo
 (``core.flatten.TreeSpec``), so the clip is a single norm and the noise
 a single Gaussian draw — no per-leaf tree_map on the hot path (the
-per-leaf fold-in below still applies verbatim to multi-leaf trees, e.g.
-the legacy wire). The mechanism lives in the same ``shard_map`` graph
+per-leaf fold-in below still applies verbatim to multi-leaf trees). The
+mechanism lives in the same ``shard_map`` graph
 as the round itself (verified by ``Server.compiled_collective_bytes`` /
 the one-``all_gather``-per-wire-dtype HLO tests).
 Accounting lives in :mod:`repro.federated.privacy.accountant`; the

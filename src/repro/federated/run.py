@@ -153,7 +153,7 @@ def build_parser() -> argparse.ArgumentParser:
                          "topology (re-padding/resharding keeps the real "
                          "silos bit-exact)")
     ap.add_argument("--wire", default="flat",
-                    choices=["flat", "fused", "legacy"],
+                    choices=["flat", "fused"],
                     help="silo->server wire layout (spec.runtime.wire)")
     ap.add_argument("--coordinator", default=None, metavar="HOST:PORT",
                     help="jax.distributed coordinator address; starts the "

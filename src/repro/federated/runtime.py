@@ -1,11 +1,8 @@
 """Compiled federated orchestration: one sharded graph per round.
 
-The host-level runtime (``repro.core.runtime``) exchanges explicit Python
-message dicts — faithful to the protocol, but it executes silos serially
-and re-enters Python every round. This module is the scale path: all J
-silos advance together inside a single ``shard_map`` over the federated
-``(silo[, model])`` mesh (``launch.mesh.build_mesh``), with the server
-virtualized into collectives:
+All J silos advance together inside a single ``shard_map`` over the
+federated ``(silo[, model])`` mesh (``launch.mesh.build_mesh``), with the
+server virtualized into collectives:
 
   * silo state (η_{L_j}, its optimizer, its data shard, and any per-silo
     strategy state such as PVI's site approximations λ_j) is stacked
@@ -33,9 +30,8 @@ virtualized into collectives:
     ``model`` rejoins the blocks before decode/aggregation, so the
     combine sees the exact (J_pad, P) matrix of the 1-D mesh and 2-D
     trajectories are bit-exact, reported ELBO included.
-    ``model > 1`` requires the flat/fused wire and an identity or int8
-    codec (custom codecs see arbitrary pytrees the runtime cannot
-    column-slice).
+    ``model > 1`` requires an identity or int8 codec (custom codecs see
+    arbitrary pytrees the runtime cannot column-slice).
 
 Multi-process execution (``jax.distributed``) runs the same SPMD graph
 over a global mesh: every process computes the identical control plane
@@ -74,7 +70,7 @@ from repro.core.flatten import TreeSpec
 from repro.core.sfvi import SFVIProblem
 from repro.federated import graph_cache
 from repro.federated.aggregation import MeanAggregator, NoCompression
-from repro.federated.metering import CommMeter
+from repro.federated.metering import CommMeter, tree_bytes
 from repro.federated.strategy import (
     DEFAULT_STRATEGY,
     ServerStrategy,
@@ -264,19 +260,17 @@ class Server:
         families, the in-graph Newton–Schulz fixed point for
         full-covariance ones) or ``"param"`` (FedAvg in parameter
         space) for SFVI-Avg's η_G merge.
-      wire: silo→server wire layout. ``"flat"`` (default) packs each
-        upload into ONE contiguous float32 vector
+      wire: how the silo→server upload runs. Every upload is packed
+        into ONE contiguous float32 vector
         (:class:`~repro.core.flatten.TreeSpec`), so DP clip+noise,
         compression, the cross-silo gather and the aggregator all
-        operate on a single (J, P) matrix — fewer HLO ops per round and
-        one int8 scale per silo instead of one per leaf. ``"fused"``
-        keeps the flat layout but runs the upload pipeline (clip + DP
-        noise + mask + int8 quantize) and the server reduction as the
+        operate on a single (J, P) matrix with one int8 scale per silo.
+        ``"flat"`` (default) runs those stages as jnp ops; ``"fused"``
+        runs the upload pipeline (clip + DP noise + mask + int8
+        quantize) and the server reduction on the same layout as the
         fused Pallas kernels of :mod:`repro.kernels.wire` — identical
         semantics (bit-exact without DP/compression; the DP noise
         stream is bit-identical by construction), fewer memory passes.
-        ``"legacy"`` keeps the per-leaf pytree wire (benchmark/debug
-        reference).
       privacy: optional :class:`~repro.federated.privacy.PrivacyPolicy`.
         When set, every silo upload is L2-clipped and Gaussian-noised
         *inside* the compiled round — before the compression hook and
@@ -295,7 +289,7 @@ class Server:
         (:class:`~repro.launch.mesh.MeshSpec`) — what
         ``ExperimentSpec.runtime.mesh`` carries. ``model > 1`` shards
         each silo row's P wire parameters across the ``model`` axis
-        (flat/fused wire with identity or int8 codec only);
+        (identity or int8 codec only);
         ``multiprocess=True`` builds the mesh over the global device
         list of a ``jax.distributed`` run and globalizes silo state,
         data and control inputs accordingly.
@@ -380,19 +374,13 @@ class Server:
                 f"{type(problem.global_family).__name__}"
             )
         self.eta_mode = eta_mode
-        if wire not in ("flat", "fused", "legacy"):
-            raise ValueError(
-                f"unknown wire layout {wire!r} (flat/fused/legacy)")
+        if wire not in ("flat", "fused"):
+            raise ValueError(f"unknown wire layout {wire!r} (flat/fused)")
         self.wire = wire
         if self.model_world > 1:
             # Model-sharding slices the (J, P) wire by columns, which
-            # needs the single-matrix layout and a codec whose payload
-            # IS that matrix (identity/int8); per-leaf wires and custom
-            # codecs carry pytrees the runtime cannot column-slice.
-            if wire == "legacy":
-                raise ValueError(
-                    "wire='legacy' cannot shard parameters along the "
-                    "model axis; use wire='flat' or 'fused' (or model=1)")
+            # needs a codec whose payload IS that matrix (identity/int8);
+            # custom codecs carry pytrees the runtime cannot column-slice.
             if _wire_codec(self.compressor) == "custom":
                 raise ValueError(
                     f"compressor {type(self.compressor).__name__} has no "
@@ -741,18 +729,15 @@ class Server:
     def bytes_up_per_silo(self, algorithm=None) -> int:
         """Post-compression upload bytes for one silo, one gather.
 
-        On the flat wire the compressor sees ONE (P,) float32 vector —
-        an int8 codec therefore pays a single 4-byte scale per silo
-        instead of one per pytree leaf. The compressor's ``wire_bytes``
-        is told the wire layout so the host meter matches what the
-        compiled collective actually gathers.
+        The compressor sees ONE (P,) float32 vector — an int8 codec
+        therefore pays a single 4-byte scale per silo — so the host
+        meter matches what the compiled collective actually gathers.
         """
-        template = self.ship_template(algorithm)
-        return self.compressor.wire_bytes(template, wire=self.wire)
+        return self.compressor.wire_bytes(self.ship_template(algorithm))
 
     def bytes_down_per_silo(self) -> int:
         """Broadcast bytes: (θ, η_G) raw; the round key is ~0 and elided."""
-        return NoCompression().wire_bytes(
+        return tree_bytes(
             {"theta": self.state["theta"], "eta_G": self.state["eta_G"]}
         )
 
@@ -1057,8 +1042,7 @@ class Server:
         the accountant's subsampling amplification holds on what is
         actually transmitted.
         """
-        if wire is not None:
-            ship = wire.pack(ship)
+        ship = wire.pack(ship)
         if fused:
             # Privatize/mask/quantize run as ONE fused pass over the
             # stacked (J, P) block after the per-silo vmap.
@@ -1075,9 +1059,7 @@ class Server:
     def _packed_reference(self, strat, ctx, wire, theta, eta_G):
         """The strategy's wire reference, packed to wire form (or None)."""
         ref = strat.reference_tree(ctx, theta, eta_G)
-        if ref is not None and wire is not None:
-            ref = wire.pack(ref)
-        return ref
+        return None if ref is None else wire.pack(ref)
 
     def accumulates(self, algorithm=None) -> bool:
         """Whether the round sums the silos' uploads one silo after another.
@@ -1096,8 +1078,7 @@ class Server:
         no option selects it.
         """
         strat = self._resolve(algorithm)
-        if (strat.cadence != "step" or self.wire == "legacy"
-                or self.privacy is not None or self.model_world > 1
+        if (strat.cadence != "step" or self.privacy is not None or self.model_world > 1
                 or _wire_codec(self.compressor) != "identity"
                 or getattr(self.aggregator, "fused_reduction", None) != "mean"):
             return False
@@ -1119,7 +1100,7 @@ class Server:
         # see a single array per silo ((J, P) once stacked). The fused
         # wire keeps the same layout but runs those stages as the Pallas
         # kernels of repro.kernels.wire on the stacked block.
-        wire = self.wire_spec(strat) if self.wire != "legacy" else None
+        wire = self.wire_spec(strat)
         fused = self.wire == "fused"
         int8 = _wire_codec(comp) == "int8"
         trim = self._fused_trim()
@@ -1209,14 +1190,12 @@ class Server:
                         enc, mask_sh,
                         _fused_keys(privacy, round_key, t, sids),
                         ref, privacy, comp, int8)
-                if wire is not None:
-                    # 2-D mesh: slice AFTER the full-row pipeline so DP
-                    # noise / int8 scales match the 1-D mesh bit-exactly,
-                    # then rejoin the gathered blocks before decoding.
-                    enc = self._shard_model_cols(enc, wire.dim)
+                # 2-D mesh: slice AFTER the full-row pipeline so DP
+                # noise / int8 scales match the 1-D mesh bit-exactly,
+                # then rejoin the gathered blocks before decoding.
+                enc = self._shard_model_cols(enc, wire.dim)
                 enc = _coalesced_all_gather(enc, "silo")
-                if wire is not None:
-                    enc = self._gather_model_cols(enc, wire.dim)
+                enc = self._gather_model_cols(enc, wire.dim)
                 hatL_sum = jax.lax.psum(jnp.sum(hatL), "silo")
 
                 if fused and int8 and trim is not None:
@@ -1231,10 +1210,9 @@ class Server:
                         mat, w_full, trim_frac=trim[0])
                         if trim is not None else agg.combine(mat, w_full))
                 else:
-                    shipped = jax.vmap(comp.decode)(enc)  # (J, P) | per leaf
+                    shipped = jax.vmap(comp.decode)(enc)  # (J, P) matrix
                     mean_g = agg.combine(shipped, w_full)
-                if wire is not None:
-                    mean_g = wire.unpack(mean_g)
+                mean_g = wire.unpack(mean_g)
                 theta, eta_G, opt_server, elbo = strat.server_step(
                     ctx, theta, eta_G, opt_server, mean_g, hatL_sum,
                     n_active, eps_G,
@@ -1255,7 +1233,7 @@ class Server:
         """Round = K local steps per silo, ONE gather + one server merge."""
         agg, comp = self.aggregator, self.compressor
         privacy = self.privacy
-        wire = self.wire_spec(strat) if self.wire != "legacy" else None
+        wire = self.wire_spec(strat)
         fused = self.wire == "fused"
         int8 = _wire_codec(comp) == "int8"
         trim = self._fused_trim()
@@ -1288,14 +1266,12 @@ class Server:
                 enc = _fused_ship(
                     enc, mask_sh, _fused_keys(privacy, round_key, 0, sids),
                     ref, privacy, comp, int8)
-            if wire is not None:
-                # 2-D mesh: slice AFTER the full-row pipeline so DP
-                # noise / int8 scales match the 1-D mesh bit-exactly,
-                # then rejoin the gathered blocks before decoding.
-                enc = self._shard_model_cols(enc, wire.dim)
+            # 2-D mesh: slice AFTER the full-row pipeline so DP
+            # noise / int8 scales match the 1-D mesh bit-exactly,
+            # then rejoin the gathered blocks before decoding.
+            enc = self._shard_model_cols(enc, wire.dim)
             enc = _coalesced_all_gather(enc, "silo")
-            if wire is not None:
-                enc = self._gather_model_cols(enc, wire.dim)
+            enc = self._gather_model_cols(enc, wire.dim)
             elbo_t = jax.lax.psum(jnp.sum(elbos, axis=0), "silo") / n_active
 
             if fused:
@@ -1308,13 +1284,9 @@ class Server:
                     shipped, w_full, trim_frac=trim[0])
                     if trim is not None else agg.combine(shipped, w_full))
                 combined = wire.unpack(vec)
-            elif wire is not None:
+            else:
                 shipped = jax.vmap(comp.decode)(enc)  # (J, P) matrix
                 combined = wire.unpack(agg.combine(shipped, w_full))
-            else:
-                shipped = jax.vmap(comp.decode)(enc)  # stacked pytree
-                combined = {k: agg.combine(v, w_full)
-                            for k, v in shipped.items()}
             theta_new, eta_new, opt_server = strat.server_update(
                 ctx, theta, eta_G, opt_server, combined, shipped,
                 w_full, n_active,

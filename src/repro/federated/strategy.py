@@ -26,7 +26,7 @@ Two cadences cover every federated-VI update rule in the zoo:
     arXiv:2302.04228).
 
 Every strategy ships ONE pytree per silo per exchange, and the runtime
-packs it over the same flat/fused ``(J, P)`` wire regardless of what the
+packs it over the same ``(J, P)`` wire regardless of what the
 tree means (gradients, parameters, natural-parameter deltas) — so DP
 clip+noise, int8 quantization, async staleness weights and the single
 coalesced all_gather apply to PVI/EP exactly as they do to the paper's
@@ -36,7 +36,7 @@ two algorithms, and DP composition threads through the one
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, ClassVar, Dict, Optional, Tuple
+from typing import Any, Callable, ClassVar, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -138,9 +138,9 @@ class StrategyContext:
 
     Frozen (hashable) so it rides into jitted closures as a constant.
     ``wire`` is the flat :class:`~repro.core.flatten.TreeSpec` bijection
-    of one upload (None on the legacy per-leaf wire); ``shipped`` values
-    passed to :meth:`ServerStrategy.server_update` are ``(J, P)``
-    matrices exactly when ``wire is not None``.
+    of one upload; ``shipped`` values passed to
+    :meth:`ServerStrategy.server_update` are ``(J, P)`` matrices of
+    uploads in that layout.
     """
 
     problem: Any
@@ -261,9 +261,8 @@ class ServerStrategy:
         """Merge the round's gathered uploads into the server state.
 
         ``combined`` is the aggregator's combine unpacked to
-        ship_template structure; ``shipped`` is the full decoded stack
-        ((J, P) matrix on the flat/fused wire, stacked pytree on the
-        legacy wire) for strategies that need every silo's upload (the
+        ship_template structure; ``shipped`` is the full decoded
+        (J, P) matrix for strategies that need every silo's upload (the
         barycenter). Returns ``(theta, eta_G, opt_server)``.
         """
         raise NotImplementedError
@@ -467,12 +466,9 @@ class SFVIAvgStrategy(ServerStrategy):
             # W2 barycenter in moment space, generic over the family's
             # moment bridge (the fused wire plugs in the fused
             # Newton–Schulz step kernel for full-covariance families).
-            if ctx.wire is not None:
-                eta_shipped = jax.vmap(
-                    lambda v: ctx.wire.unpack(v)["eta_G"]
-                )(shipped)
-            else:
-                eta_shipped = shipped["eta_G"]
+            eta_shipped = jax.vmap(
+                lambda v: ctx.wire.unpack(v)["eta_G"]
+            )(shipped)
             sqrtm_kw = (
                 {"sqrtm": wire_kernels.sqrtm_newton_schulz_fused}
                 if ctx.fused else {})

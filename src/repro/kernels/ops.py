@@ -28,14 +28,6 @@ def interpret_default() -> bool:
     return jax.default_backend() == "cpu"
 
 
-def __getattr__(name: str):
-    # Legacy alias: ``ops.INTERPRET`` used to be computed at import time,
-    # which initialized the backend as a side effect of the import.
-    if name == "INTERPRET":
-        return interpret_default()
-    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
-
-
 @partial(jax.jit, static_argnames=("causal", "window", "q_offset", "block_q",
                                    "block_kv", "interpret"))
 def flash_attention(

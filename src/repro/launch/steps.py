@@ -167,7 +167,7 @@ def make_train_step(cfg: ArchConfig, num_silos: int, lr: float = 3e-4,
         if silo_mask is not None:
             # Partial silo participation (paper §1): only active silos
             # contribute; J/|active| rescale keeps the estimator unbiased
-            # (matches core/runtime.py::SFVIServer.run participation).
+            # (the J/n_active rescale of the compiled Server's ELBO).
             m = silo_mask.astype(jnp.float32)
             Lj = Lj * m * (num_silos / jnp.maximum(jnp.sum(m), 1.0))
         elbo = L0 + jnp.sum(Lj)

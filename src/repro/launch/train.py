@@ -26,8 +26,8 @@ from repro.configs import get_config
 from repro.data.synthetic import make_token_stream
 from repro.checkpoint.io import CheckpointManager
 from repro.federated import (CommMeter, ExperimentSpec, MeshSpec, ModelSpec,
-                             NoCompression, OptimizerSpec, RuntimeSpec,
-                             Scenario, run_rounds)
+                             OptimizerSpec, RuntimeSpec, Scenario, run_rounds,
+                             tree_bytes)
 from repro.launch import steps as S
 from repro.launch.mesh import build_mesh
 from repro.models.backbone import transformer as T
@@ -179,8 +179,8 @@ def main(argv=None):
     # state.eta_G is silo-stacked (silos, n_G): each silo ships only its own
     # slice, so the per-silo cost divides the stacked size by --silos.
     meter = CommMeter()
-    theta_bytes = NoCompression().wire_bytes({"theta": state.theta})
-    eta_bytes = NoCompression().wire_bytes({"eta_G": state.eta_G})
+    theta_bytes = tree_bytes({"theta": state.theta})
+    eta_bytes = tree_bytes({"eta_G": state.eta_G})
     if args.algo == "avg":
         per_silo = theta_bytes + eta_bytes // args.silos
     else:

@@ -3,11 +3,15 @@
 Covers the acceptance surface of the API redesign:
   * spec JSON round trip, including scenario/privacy fields;
   * save -> resume bit-exactness vs an uninterrupted run;
-  * the deprecated eager adapters (SFVIServer / SFVIAvgServer) produce
-    the compiled Server's trajectories exactly (K = 1 equivalence);
+  * the retired wire layout is refused on resume, and ``repro.core``
+    imports nothing from ``repro.federated``;
   * registry lookup + the CLI's --list-models / --dump-spec paths.
 """
+import dataclasses
 import json
+import os
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -18,10 +22,7 @@ from repro.core import (
     CholeskyGaussian,
     ConditionalGaussian,
     DiagGaussian,
-    SFVIAvgServer,
     SFVIProblem,
-    SFVIServer,
-    Silo,
     StructuredModel,
 )
 from repro.core.families import LowRankGaussian
@@ -32,10 +33,10 @@ from repro.federated import (
     FamilySpec,
     ModelSpec,
     OptimizerSpec,
+    RuntimeSpec,
     Scenario,
     Server,
     build,
-    stack_silos,
 )
 from repro.federated import run as cli
 from repro.models.paper.registry import get_model, list_models, model_names
@@ -175,19 +176,22 @@ class TestFamilyOverrides:
 
     def test_legacy_wire_run_resumes_on_legacy_wire(self, tmp_path):
         """The wire layout is recorded in the checkpoint meta: a run
-        built with wire='legacy' under DP+int8 (layout-dependent noise
-        keys and scales) must resume on the SAME layout, bit-exactly."""
+        built on the fused wire under DP+int8 must resume on the SAME
+        layout, bit-exactly."""
         sc = Scenario(algorithm="sfvi_avg", compression="int8",
                       dp_noise=0.5, dp_clip=0.9)
-        spec = self._spec(None, scenario=sc, rounds=4)
-        full = build(spec, wire="legacy")
+        spec = dataclasses.replace(self._spec(None, scenario=sc, rounds=4),
+                                   runtime=RuntimeSpec(wire="fused"))
+        full = build(spec)
         full.run()
 
-        part = build(spec, wire="legacy")
+        part = build(spec)
         part.run(2)
         part.save(str(tmp_path))
+        with open(Experiment._meta_path(str(tmp_path), 2)) as f:
+            assert json.load(f)["wire"] == "fused"
         resumed = Experiment.resume(str(tmp_path))
-        assert resumed.server.wire == "legacy"
+        assert resumed.server.wire == "fused"
         resumed.run()
         _assert_trees_bit_equal(_run_state(full), _run_state(resumed))
 
@@ -364,18 +368,37 @@ class TestSaveResume:
             Experiment.resume(str(tmp_path))
 
 
-# ---------------------------------------------------------------------------
-# Adapter equivalence: the deprecated eager API runs the compiled graph
-# ---------------------------------------------------------------------------
+    def test_resume_refuses_a_legacy_wire_checkpoint(self, tmp_path):
+        """A checkpoint whose meta records the retired per-leaf layout
+        comes from outside this program: resume names the two layouts
+        it runs instead of building a Server for it."""
+        exp = build(self._spec())
+        exp.run(1)
+        exp.save(str(tmp_path))
+        meta_path = Experiment._meta_path(str(tmp_path), 1)
+        with open(meta_path) as f:
+            meta = json.load(f)
+        meta["wire"] = "legacy"
+        with open(meta_path, "w") as f:
+            json.dump(meta, f)
+        with pytest.raises(ValueError, match=r"'legacy' \(flat/fused\)"):
+            Experiment.resume(str(tmp_path))
 
 
-def _global_only_problem(dG=3):
-    model = StructuredModel(
-        global_dim=dG, local_dim=0,
-        log_prior_global=lambda th, zg: -0.5 * jnp.sum((zg - th["m"]) ** 2),
-        log_local=lambda th, zg, zl, d: -0.5 * jnp.sum((d["y"] - zg[None, :]) ** 2),
-    )
-    return SFVIProblem(model, DiagGaussian(dG))
+def test_core_imports_nothing_from_federated():
+    """The library layer stands below the runtime: importing repro.core
+    loads no repro.federated module."""
+    code = ("import sys, repro.core; "
+            "print([m for m in sys.modules "
+            "if m.startswith('repro.federated')])")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join(
+                   [os.path.join(os.path.dirname(os.path.dirname(
+                       os.path.abspath(__file__))), "src"),
+                    os.environ.get("PYTHONPATH", "")]))
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, check=True)
+    assert out.stdout.strip() == "[]", out.stdout
 
 
 def _hier_problem(dG=3, dL=2):
@@ -396,146 +419,20 @@ def _datas(key, J, n, d):
 
 
 class TestAdapterEquivalence:
-    def test_sfvi_adapter_matches_server_k1(self):
-        """Legacy SFVIServer == compiled Server, bit for bit, at K=1."""
-        lr, J, n = 0.05, 3, 4
-        prob = _global_only_problem()
-        theta = {"m": jnp.asarray(0.2)}
-        eta_G = prob.global_family.init(jax.random.PRNGKey(1), mu_scale=0.4)
-        datas = _datas(jax.random.PRNGKey(2), J, n, 3)
-        silos = [Silo(j, prob, datas[j], None, None, n) for j in range(J)]
-
-        with pytest.warns(DeprecationWarning):
-            legacy = SFVIServer(prob, silos, theta, eta_G, sgd(lr), seed=7)
-        direct = Server(prob, datas, theta, eta_G, num_obs=[n] * J,
-                        server_opt=sgd(lr), eta_mode="param", seed=7)
-        h_legacy = legacy.run(3)
-        h_direct = direct.run(3, algorithm="sfvi", local_steps=1)
-
-        _assert_trees_bit_equal(legacy.theta, direct.theta)
-        _assert_trees_bit_equal(legacy.eta_G, direct.eta_G)
-        assert h_legacy["elbo"] == h_direct["elbo"]
-        assert h_legacy["bytes_up"] == h_direct["bytes_up"]
-
-    def test_sfvi_adapter_matches_server_with_locals(self):
-        """Same, with local latents: caller-initialized η_{L_j} are
-        honoured and the trajectories coincide exactly."""
-        lr, J, n = 0.05, 3, 4
-        prob = _hier_problem()
-        theta = {"m": jnp.asarray(0.1)}
-        eta_G = prob.global_family.init(jax.random.PRNGKey(3), mu_scale=0.4)
-        datas = _datas(jax.random.PRNGKey(4), J, n, 2)
-        key = jax.random.PRNGKey(9)
-        etas_L = [prob.local_family.init(jax.random.fold_in(key, j))
-                  for j in range(J)]
-        silos = [Silo(j, prob, datas[j], etas_L[j], sgd(lr), n)
-                 for j in range(J)]
-
-        with pytest.warns(DeprecationWarning):
-            legacy = SFVIServer(prob, silos, theta, eta_G, sgd(lr), seed=11)
-        direct = Server(prob, datas, theta, eta_G, num_obs=[n] * J,
-                        server_opt=sgd(lr), local_opt=sgd(lr),
-                        eta_mode="param", seed=11)
-        direct.state["eta_L"] = stack_silos(etas_L)
-
-        legacy.run(2)
-        direct.run(2, algorithm="sfvi", local_steps=1)
-
-        _assert_trees_bit_equal(legacy.theta, direct.theta)
-        _assert_trees_bit_equal(legacy.eta_G, direct.eta_G)
-        _assert_trees_bit_equal(legacy._compiled.eta_L, direct.eta_L)
-        # And the adapter wrote the updated slices back into the Silos.
-        for j, silo in enumerate(silos):
-            _assert_trees_bit_equal(
-                silo.eta_L,
-                jax.tree_util.tree_map(lambda x: x[j], direct.eta_L))
-
-    def test_avg_adapter_runs_real_cholesky_barycenter(self):
-        """The adapter no longer downgrades CholeskyGaussian to
-        parameter-space averaging: it runs the generic in-graph W2
-        barycenter and matches the direct Server bit for bit."""
-        lr, J, n, K = 0.03, 3, 4, 2
-        dG = 3
-        model = StructuredModel(
-            global_dim=dG, local_dim=2,
-            log_prior_global=lambda th, zg: -0.5 * jnp.sum((zg - th["m"]) ** 2),
-            log_local=lambda th, zg, zl, d: (
-                -0.5 * jnp.sum((zl - jnp.mean(zg)) ** 2)
-                - 0.5 * jnp.sum((d["y"] - zl[None, :]) ** 2)
-            ),
-        )
-        prob = SFVIProblem(model, CholeskyGaussian(dG),
-                           ConditionalGaussian(2, dG))
-        theta = {"m": jnp.asarray(0.1)}
-        eta_G = prob.global_family.init(jax.random.PRNGKey(5), mu_scale=0.4)
-        datas = _datas(jax.random.PRNGKey(6), J, n, 2)
-        key = jax.random.PRNGKey(13)
-        etas_L = [prob.local_family.init(jax.random.fold_in(key, j))
-                  for j in range(J)]
-        silos = [Silo(j, prob, datas[j], etas_L[j], sgd(lr), n)
-                 for j in range(J)]
-
-        import warnings as _w
-
-        with _w.catch_warnings(record=True) as caught:
-            _w.simplefilter("always")
-            legacy = SFVIAvgServer(prob, silos, theta, eta_G,
-                                   lambda: sgd(lr), seed=17)
-        # Deprecation only — the barycenter->param downgrade warning is gone.
-        assert all(issubclass(w.category, DeprecationWarning) for w in caught)
-        assert legacy._compiled.eta_mode == "barycenter"
-
-        direct = Server(prob, datas, theta, eta_G, num_obs=[n] * J,
-                        server_opt=sgd(lr), local_opt=sgd(lr),
-                        eta_mode="barycenter", seed=17)
-        direct.state["eta_L"] = stack_silos(etas_L)
-        legacy.run(2, local_steps=K)
-        direct.run(2, algorithm="sfvi_avg", local_steps=K)
-        _assert_trees_bit_equal(legacy.theta, direct.theta)
-        _assert_trees_bit_equal(legacy.eta_G, direct.eta_G)
+    """What the retired eager SFVI-Avg adapter guaranteed, on Server."""
 
     def test_avg_adapter_rejects_family_without_moments(self):
-        """A global family with no to_moments has no barycenter: the
-        adapter fails loudly instead of silently averaging parameters."""
+        """A global family with no to_moments has no barycenter: SFVI-Avg
+        fails loudly instead of silently averaging parameters."""
         class NoMoments(DiagGaussian):
             has_moments = False
 
         prob = _hier_problem()
         prob = SFVIProblem(prob.model, NoMoments(3), prob.local_family)
         datas = _datas(jax.random.PRNGKey(2), 2, 4, 2)
-        silos = [Silo(j, prob, datas[j],
-                      prob.local_family.init(jax.random.PRNGKey(j)),
-                      sgd(0.05), 4) for j in range(2)]
-        with pytest.warns(DeprecationWarning), \
-                pytest.raises(ValueError, match="to_moments"):
-            SFVIAvgServer(prob, silos, {"m": jnp.asarray(0.1)},
-                          NoMoments(3).init(jax.random.PRNGKey(1)),
-                          lambda: sgd(0.05))
-
-    def test_avg_adapter_matches_server(self):
-        """Legacy SFVIAvgServer == compiled Server (sfvi_avg), bit for bit."""
-        lr, J, n, K = 0.03, 3, 4, 3
-        prob = _hier_problem()
-        theta = {"m": jnp.asarray(0.1)}
-        eta_G = prob.global_family.init(jax.random.PRNGKey(5), mu_scale=0.4)
-        datas = _datas(jax.random.PRNGKey(6), J, n, 2)
-        key = jax.random.PRNGKey(13)
-        etas_L = [prob.local_family.init(jax.random.fold_in(key, j))
-                  for j in range(J)]
-        silos = [Silo(j, prob, datas[j], etas_L[j], sgd(lr), n)
-                 for j in range(J)]
-
-        with pytest.warns(DeprecationWarning):
-            legacy = SFVIAvgServer(prob, silos, theta, eta_G,
-                                   lambda: sgd(lr), seed=17)
-        direct = Server(prob, datas, theta, eta_G, num_obs=[n] * J,
-                        server_opt=sgd(lr), local_opt=sgd(lr),
-                        eta_mode="barycenter", seed=17)
-        direct.state["eta_L"] = stack_silos(etas_L)
-
-        h_legacy = legacy.run(2, local_steps=K)
-        h_direct = direct.run(2, algorithm="sfvi_avg", local_steps=K)
-
-        _assert_trees_bit_equal(legacy.theta, direct.theta)
-        _assert_trees_bit_equal(legacy.eta_G, direct.eta_G)
-        assert h_legacy["elbo"] == h_direct["elbo"]
+        with pytest.raises(ValueError, match="to_moments"):
+            srv = Server(prob, datas, {"m": jnp.asarray(0.1)},
+                         NoMoments(3).init(jax.random.PRNGKey(1)),
+                         num_obs=[4, 4], server_opt=sgd(0.05),
+                         local_opt=sgd(0.05), strategy="sfvi_avg")
+            srv.run(1, local_steps=2)

@@ -3,7 +3,8 @@
 Covers the acceptance surface of the mesh redesign:
   * MeshSpec / RuntimeSpec JSON round trips and the CLI parse form;
   * build_mesh as the single factory (shapes, validation, axis helpers);
-  * the deprecated out-of-band ``wire=`` kwarg warns once and still wins;
+  * the wire layout comes from ``spec.runtime`` (``build`` takes no
+    ``wire=`` keyword);
   * graph_cache tokens split on mesh shape (the stale-graph regression);
   * (slow, 8 forced host devices) J=64 trajectories: parameter state is
     bit-exact across every silo device count, and the 2-D
@@ -20,7 +21,6 @@ import textwrap
 import pytest
 
 from repro.federated import ExperimentSpec, MeshSpec, ModelSpec, RuntimeSpec
-from repro.federated import api as api_mod
 from repro.federated import graph_cache
 from repro.federated.api import build
 from repro.federated.scheduler import Scenario
@@ -105,22 +105,11 @@ class TestBuildMesh:
 
 
 class TestWireKwargDeprecation:
-    def test_build_warns_once_and_kwarg_wins(self):
-        api_mod._WIRE_KWARG_WARNED = False
-        spec = _toy_spec(runtime=RuntimeSpec(wire="flat"))
-        with pytest.warns(DeprecationWarning, match="wire= kwarg"):
-            exp = build(spec, wire="legacy")
-        assert exp.server.wire == "legacy"
-        # Once per process: the second use is silent.
-        import warnings as _w
-        with _w.catch_warnings():
-            _w.simplefilter("error")
-            build(spec, wire="legacy")
-        api_mod._WIRE_KWARG_WARNED = False
+    """The layout rides the spec; the old ``wire=`` keyword is gone."""
 
     def test_spec_runtime_wire_is_the_default(self):
-        exp = build(_toy_spec(runtime=RuntimeSpec(wire="legacy")))
-        assert exp.server.wire == "legacy"
+        exp = build(_toy_spec(runtime=RuntimeSpec(wire="fused")))
+        assert exp.server.wire == "fused"
 
 
 class TestGraphCacheToken:

@@ -13,7 +13,6 @@ from repro.core import (
     DiagGaussian,
     SFVIProblem,
     StructuredModel,
-    tree_add,
 )
 
 # NOTE: float32 throughout (x64 would leak into the whole pytest session);
@@ -69,7 +68,8 @@ def test_federated_equals_centralized_gradient(num_silos, dG, dL, use_coupling, 
         gtj, gej, _, _ = prob.silo_grads(
             theta, eta_G, etas_L[j], eps_G, eps_L[j], datas[j]
         )
-        g_theta, g_eta = tree_add(g_theta, gtj), tree_add(g_eta, gej)
+        g_theta = jax.tree_util.tree_map(jnp.add, g_theta, gtj)
+        g_eta = jax.tree_util.tree_map(jnp.add, g_eta, gej)
 
     # Centralized single-graph gradient.
     cent = jax.grad(

@@ -7,13 +7,12 @@ import pytest
 from repro.core import (
     ConditionalGaussian,
     DiagGaussian,
-    SFVIAvgServer,
     SFVIProblem,
-    SFVIServer,
-    Silo,
     StructuredModel,
-    tree_bytes,
+    family_barycenter,
 )
+from repro.federated import MeanAggregator, RoundScheduler, Server
+from repro.federated.metering import tree_bytes
 from repro.optim import adam
 
 
@@ -33,31 +32,32 @@ def _toy_problem(dG=2, dL=3):
     return SFVIProblem(model, DiagGaussian(dG), ConditionalGaussian(dL, dG))
 
 
-def _make_silos(prob, J=3, n=5, lr=5e-2, seed=0):
+def _server(prob, J=3, n=5, lr=5e-2, seed=0, strategy="sfvi"):
+    """A Server over J silos of n standard-normal rows each; SFVI
+    averages η_G in parameter space, SFVI-Avg through the barycenter."""
     datas = [
-        jax.random.normal(jax.random.PRNGKey(100 + seed + j), (n, prob.model.local_dim))
+        jax.random.normal(jax.random.PRNGKey(100 + seed + j),
+                          (n, prob.model.local_dim))
         for j in range(J)
     ]
-    return [
-        Silo(j, prob, datas[j], prob.local_family.init(jax.random.PRNGKey(seed + j)),
-             adam(lr), n)
-        for j in range(J)
-    ]
+    return Server(
+        prob, datas, {}, prob.global_family.init(jax.random.PRNGKey(1)),
+        num_obs=[n] * J, server_opt=adam(lr), local_opt=adam(lr),
+        eta_mode="param" if strategy == "sfvi" else "barycenter",
+        strategy=strategy, seed=seed)
 
 
 class TestSFVIServer:
+    """Algorithm 1 (one gather per local step) on the compiled Server."""
+
     def test_elbo_improves(self):
-        prob = _toy_problem()
-        silos = _make_silos(prob)
-        srv = SFVIServer(prob, silos, {}, prob.global_family.init(jax.random.PRNGKey(1)), adam(5e-2))
-        h = srv.run(200)
+        srv = _server(_toy_problem())
+        h = srv.run(200, local_steps=1)
         assert np.mean(h["elbo"][-20:]) > np.mean(h["elbo"][:20])
 
     def test_no_nans(self):
-        prob = _toy_problem()
-        silos = _make_silos(prob)
-        srv = SFVIServer(prob, silos, {}, prob.global_family.init(jax.random.PRNGKey(1)), adam(5e-2))
-        h = srv.run(50)
+        srv = _server(_toy_problem())
+        h = srv.run(50, local_steps=1)
         assert np.all(np.isfinite(h["elbo"]))
         for leaf in jax.tree_util.tree_leaves(srv.eta_G):
             assert bool(jnp.all(jnp.isfinite(leaf)))
@@ -65,32 +65,33 @@ class TestSFVIServer:
     def test_communication_is_global_sized_only(self):
         """The up-link carries ONLY global-shaped gradients — nothing scaling
         with local latent dims or data size (the paper's privacy property)."""
-        prob = _toy_problem(dG=2, dL=50)
-        silos = _make_silos(prob, J=2, n=40)
-        srv = SFVIServer(prob, silos, {}, prob.global_family.init(jax.random.PRNGKey(1)), adam(1e-2))
-        h = srv.run(3)
+        srv = _server(_toy_problem(dG=2, dL=50), J=2, n=40, lr=1e-2)
+        h = srv.run(3, local_steps=1)
         # up-link per silo per round = g_theta (empty) + g_eta (2*dG floats)
         expected_up_per_silo = 2 * 2 * 4  # mu+log_sigma, dG=2, f32
+        assert srv.bytes_up_per_silo() == expected_up_per_silo
         assert h["bytes_up"][0] == 2 * expected_up_per_silo
 
     def test_partial_participation_still_converges(self):
-        prob = _toy_problem()
-        silos = _make_silos(prob, J=4)
-        srv = SFVIServer(prob, silos, {}, prob.global_family.init(jax.random.PRNGKey(1)), adam(5e-2))
-        h = srv.run(300, participation=0.5)
+        srv = _server(_toy_problem(), J=4)
+        h = srv.run(300, local_steps=1,
+                    scheduler=RoundScheduler(4, participation=0.5, seed=0))
+        assert min(h["n_active"]) < 4  # silos really sat rounds out
         assert np.mean(h["elbo"][-20:]) > np.mean(h["elbo"][:20])
 
     def test_local_params_never_in_messages(self):
-        """Structural privacy check: reply trees contain no local-dim leaves."""
-        prob = _toy_problem(dG=2, dL=17)
-        silo = _make_silos(prob, J=1)[0]
-        eps_G = jax.random.normal(jax.random.PRNGKey(0), (2,))
-        reply = silo.sfvi_step({"theta": {}, "eta_G": prob.global_family.init(jax.random.PRNGKey(1)), "eps_G": eps_G})
-        for leaf in jax.tree_util.tree_leaves(reply):
-            assert 17 not in leaf.shape
+        """Structural privacy check: no leaf of a silo's upload has the
+        local dimension."""
+        srv = _server(_toy_problem(dG=2, dL=17), J=1)
+        for algorithm in ("sfvi", "sfvi_avg"):
+            for leaf in jax.tree_util.tree_leaves(
+                    srv.ship_template(algorithm)):
+                assert 17 not in leaf.shape
 
 
 class TestSFVIAvgServer:
+    """Algorithm 2 (K local steps, one barycenter merge) on the Server."""
+
     def test_elbo_improves(self):
         """Late-window mean ELBO beats the early window by more than the
         estimator noise. The per-round ELBO is a single-sample MC
@@ -101,11 +102,7 @@ class TestSFVIAvgServer:
         and the tolerance is derived from the within-window variance of
         the estimates themselves (2x the pooled standard error) instead
         of a magic constant."""
-        prob = _toy_problem()
-        silos = _make_silos(prob, lr=2e-2, seed=0)
-        srv = SFVIAvgServer(prob, silos, {},
-                            prob.global_family.init(jax.random.PRNGKey(1)),
-                            lambda: adam(2e-2), seed=0)
+        srv = _server(_toy_problem(), lr=2e-2, strategy="sfvi_avg")
         h = srv.run(12, local_steps=3)
         elbo = np.asarray(h["elbo"])
         early, late = elbo[:3], elbo[-3:]
@@ -119,12 +116,10 @@ class TestSFVIAvgServer:
         """Communication efficiency: m local steps per round -> 1 round of
         communication instead of m (the paper's whole point for SFVI-Avg)."""
         prob = _toy_problem()
-        silos_a = _make_silos(prob)
-        srv_a = SFVIServer(prob, silos_a, {}, prob.global_family.init(jax.random.PRNGKey(1)), adam(5e-2))
-        h_a = srv_a.run(100)
+        srv_a = _server(prob)
+        h_a = srv_a.run(100, local_steps=1)
 
-        silos_b = _make_silos(prob)
-        srv_b = SFVIAvgServer(prob, silos_b, {}, prob.global_family.init(jax.random.PRNGKey(1)), lambda: adam(5e-2))
+        srv_b = _server(prob, strategy="sfvi_avg")
         h_b = srv_b.run(4, local_steps=25)  # same 100 gradient steps
 
         assert srv_b.comm.rounds < srv_a.comm.rounds
@@ -134,11 +129,11 @@ class TestSFVIAvgServer:
 
     def test_barycenter_of_identical_silos_is_identity(self):
         """If all silos return the same η_G, averaging must not move it."""
-        prob = _toy_problem()
-        fam = prob.global_family
+        fam = _toy_problem().global_family
         eta = fam.init(jax.random.PRNGKey(0))
-        srv = SFVIAvgServer(prob, _make_silos(prob), {}, eta, lambda: adam(1e-2))
-        out = srv._barycenter([eta, eta, eta])
+        stacked = jax.tree_util.tree_map(lambda x: jnp.stack([x] * 3), eta)
+        out = family_barycenter(fam, stacked, jnp.ones((3,)),
+                                MeanAggregator())
         for k in eta:
             np.testing.assert_allclose(out[k], eta[k], rtol=1e-5)
 

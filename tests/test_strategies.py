@@ -158,7 +158,7 @@ class TestRegistry:
 
 
 class TestLegacyEquivalence:
-    @pytest.mark.parametrize("wire", ["flat", "fused", "legacy"])
+    @pytest.mark.parametrize("wire", ["flat", "fused"])
     def test_bit_exact_round_trajectories(self, wire):
         """Registry SFVI / SFVI-Avg == the pre-refactor Server, bit for
         bit over 3 rounds: elbo history and full (θ, η_G, η_L) state —

@@ -3,22 +3,26 @@
 Covers:
   * ``TreeSpec`` — the pytree <-> one-f32-vector bijection (structure,
     dtypes, jit-safety, empty-subtree edge cases);
-  * flat vs legacy wire equivalence: without DP/compression the packed
-    path is a pure relayout, so trajectories must agree BIT FOR BIT;
+  * flat wire vs the frozen per-leaf oracle (``tests/_legacy_server.py``):
+    without DP/compression the packed path is a pure relayout, so
+    trajectories must agree BIT FOR BIT;
+  * the retired per-leaf layout is refused by Server, RuntimeSpec and
+    the CLI;
   * fused vs flat wire equivalence end to end: the fused Pallas kernels
     replay the SAME op sequence and the SAME DP noise stream inside the
     compiled round, so trajectories agree bit for bit without
     compression and under DP — and stay tolerance-close when int8
     requantization + async fractional weights reorder the arithmetic;
   * save -> resume ACROSS a wire-mode change (flat <-> fused);
-  * wire accounting: one int8 scale per SILO (not per leaf) on the flat
-    path;
+  * wire accounting: one packed f32 row per silo, one int8 scale per
+    SILO (not per leaf);
   * the compiled-graph invariance (subprocess, 4 forced host devices):
     a DP + int8 round lowers to exactly ONE all_gather per wire dtype
     (s8 payload + f32 scale), and an uncompressed round to exactly one
     f32 gather — the §3.2 exchange structure, on BOTH the flat and the
     fused wire.
 """
+import dataclasses
 import os
 import subprocess
 import sys
@@ -28,6 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from _legacy_server import LegacyServer
 
 from repro.core import (
     ConditionalGaussian,
@@ -46,10 +51,12 @@ from repro.federated import (
     NoCompression,
     OptimizerSpec,
     PrivacyPolicy,
+    RuntimeSpec,
     Scenario,
     Server,
     build,
 )
+from repro.federated import run as cli
 from repro.optim.sgd import sgd
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -126,12 +133,14 @@ def _hier_problem(dG=3, dL=2):
     )
 
 
-def _server(wire, compressor=None, privacy=None, seed=11):
+def _server(wire, compressor=None, privacy=None, seed=11, cls=Server):
+    """A 3-silo hierarchical federation; ``cls=LegacyServer`` builds the
+    frozen oracle, whose ``wire="legacy"`` keeps the per-leaf upload."""
     prob = _hier_problem()
     datas = [{"y": jax.random.normal(
         jax.random.fold_in(jax.random.PRNGKey(9), j), (4, 2))}
         for j in range(3)]
-    return Server(
+    return cls(
         prob, datas, {"m": jnp.asarray(0.2)},
         prob.global_family.init(jax.random.PRNGKey(1)),
         server_opt=sgd(3e-2), local_opt=sgd(3e-2),
@@ -148,10 +157,11 @@ def _flat(tree):
 class TestFlatVsLegacy:
     @pytest.mark.parametrize("algorithm", ["sfvi", "sfvi_avg"])
     def test_bit_exact_without_dp_or_compression(self, algorithm):
-        """Packing is a relayout: flat and legacy wires must produce the
-        SAME trajectory bit for bit when no codec/noise touches the
-        payload (per-coordinate reduction order is unchanged)."""
-        a, b = _server("flat"), _server("legacy")
+        """Packing is a relayout: the flat wire and the oracle's per-leaf
+        wire must produce the SAME trajectory bit for bit when no
+        codec/noise touches the payload (per-coordinate reduction order
+        is unchanged)."""
+        a, b = _server("flat"), _server("legacy", cls=LegacyServer)
         a.run(3, algorithm=algorithm, local_steps=2)
         b.run(3, algorithm=algorithm, local_steps=2)
         for k in ("theta", "eta_G", "eta_L"):
@@ -161,7 +171,7 @@ class TestFlatVsLegacy:
         """One scale per silo instead of per leaf changes quantization
         noise, not semantics: trajectories stay close."""
         a = _server("flat", compressor=Int8Compressor())
-        b = _server("legacy", compressor=Int8Compressor())
+        b = _server("legacy", compressor=Int8Compressor(), cls=LegacyServer)
         a.run(3, algorithm="sfvi_avg", local_steps=2)
         b.run(3, algorithm="sfvi_avg", local_steps=2)
         np.testing.assert_allclose(_flat(a.eta_G), _flat(b.eta_G),
@@ -170,6 +180,27 @@ class TestFlatVsLegacy:
     def test_rejects_unknown_wire(self):
         with pytest.raises(ValueError, match="wire layout"):
             _server("pigeon")
+
+    @pytest.mark.parametrize("where", ["server", "runtime_spec", "cli"])
+    def test_legacy_layout_is_refused(self, where, capsys):
+        """The per-leaf layout is retired: every entry point that takes a
+        layout accepts only flat and fused."""
+        if where == "cli":
+            with pytest.raises(SystemExit):
+                cli.build_parser().parse_args(["--wire", "legacy"])
+            err = capsys.readouterr().err
+            assert "--wire: invalid choice: 'legacy'" in err
+            assert "flat" in err and "fused" in err
+            return
+        with pytest.raises(ValueError, match=r"'legacy' \(flat/fused\)"):
+            if where == "server":
+                _server("legacy")
+            else:
+                RuntimeSpec(wire="legacy")
+
+
+def _on_wire(spec, wire):
+    return dataclasses.replace(spec, runtime=RuntimeSpec(wire=wire))
 
 
 def _toy_spec(scenario, *, gfam=None, rounds=4):
@@ -222,7 +253,7 @@ class TestFusedVsFlat:
                       async_cfg=AsyncConfig(buffer_size=2,
                                             latency="lognormal"))
         spec = _toy_spec(sc, rounds=5)
-        a, b = build(spec, wire="flat"), build(spec, wire="fused")
+        a, b = build(_on_wire(spec, "flat")), build(_on_wire(spec, "fused"))
         a.run()
         b.run()
         np.testing.assert_allclose(_flat(a.eta_G), _flat(b.eta_G),
@@ -237,7 +268,7 @@ class TestFusedVsFlat:
         — same normalization, same iteration, bit-identical states."""
         spec = _toy_spec(Scenario(algorithm="sfvi_avg"),
                          gfam=FamilySpec("cholesky"), rounds=3)
-        a, b = build(spec, wire="flat"), build(spec, wire="fused")
+        a, b = build(_on_wire(spec, "flat")), build(_on_wire(spec, "fused"))
         a.run()
         b.run()
         for k in ("theta", "eta_G", "eta_L"):
@@ -248,16 +279,17 @@ class TestFusedVsFlat:
     def test_resume_across_wire_mode_change(self, tmp_path, wires):
         """A checkpoint taken on one wire continues on the other with no
         trajectory change (no DP/compression -> both wires are the same
-        bit-exact program), via Experiment.resume(..., wire=...)."""
+        bit-exact program), via a resume spec on the other layout."""
         first, second = wires
         spec = _toy_spec(Scenario(algorithm="sfvi_avg"), rounds=4)
-        full = build(spec, wire=first)
+        full = build(_on_wire(spec, first))
         full.run()
 
-        part = build(spec, wire=first)
+        part = build(_on_wire(spec, first))
         part.run(2)
         part.save(str(tmp_path))
-        resumed = Experiment.resume(str(tmp_path), wire=second)
+        resumed = Experiment.resume(str(tmp_path),
+                                    spec=_on_wire(spec, second))
         assert resumed.server.wire == second
         resumed.run()
         for k in ("theta", "eta_G", "eta_L"):
@@ -266,7 +298,7 @@ class TestFusedVsFlat:
 
     def test_resume_defaults_to_recorded_wire(self, tmp_path):
         spec = _toy_spec(Scenario(algorithm="sfvi_avg"), rounds=3)
-        part = build(spec, wire="fused")
+        part = build(_on_wire(spec, "fused"))
         part.run(1)
         part.save(str(tmp_path))
         assert Experiment.resume(str(tmp_path)).server.wire == "fused"
@@ -274,20 +306,21 @@ class TestFusedVsFlat:
 
 class TestWireAccounting:
     def test_int8_pays_one_scale_per_silo(self):
-        srv = _server("flat", compressor=Int8Compressor())
-        P = srv.wire_spec("sfvi").dim
-        assert srv.bytes_up_per_silo("sfvi") == P + 4  # payload + ONE scale
-        legacy = _server("legacy", compressor=Int8Compressor())
-        n_leaves = len(jax.tree_util.tree_leaves(legacy.ship_template("sfvi")))
-        assert legacy.bytes_up_per_silo("sfvi") == P + 4 * n_leaves
+        for wire in ("flat", "fused"):
+            srv = _server(wire, compressor=Int8Compressor())
+            P = srv.wire_spec("sfvi").dim
+            # payload + ONE scale, however many leaves the upload has
+            assert srv.bytes_up_per_silo("sfvi") == P + 4
+        n_leaves = len(jax.tree_util.tree_leaves(srv.ship_template("sfvi")))
         assert n_leaves > 1  # the saving is real
 
     def test_uncompressed_bytes_identical_across_wires(self):
-        flat, legacy = _server("flat"), _server("legacy")
+        flat, fused = _server("flat"), _server("fused")
         for algo in ("sfvi", "sfvi_avg"):
+            P = flat.wire_spec(algo).dim
             assert flat.bytes_up_per_silo(algo) == \
-                legacy.bytes_up_per_silo(algo) == \
-                NoCompression().wire_bytes(flat.ship_template(algo))
+                fused.bytes_up_per_silo(algo) == \
+                NoCompression().wire_bytes(flat.ship_template(algo)) == 4 * P
 
 
 # ---------------------------------------------------------------------------
